@@ -281,13 +281,11 @@ class PersistentEngine:
     def __init__(self, cfg: ModelConfig, params: dict, ecfg: EngineConfig):
         if not cfg.has_moe:
             raise ValueError(f"{cfg.name} has no MoE layers; SliceMoE "
-                             "expert caching is inapplicable (see DESIGN.md)")
+                             "expert caching is inapplicable")
         self.cfg = cfg
         self.ecfg = ecfg
         self.qparams, self.store, self.layer_map = quantize_moe_params(
-            params, cfg, ecfg.mat,
-            quant_execution=ecfg.policy.quant_execution)
-        self.float_params = params
+            params, cfg, ecfg.mat)
         self.n_moe_layers = len(self.layer_map)
         self.n_experts = cfg.moe.n_experts
 
@@ -409,12 +407,8 @@ class PersistentEngine:
 
         if quant_execution is None:
             quant_execution = self.ecfg.policy.quant_execution
-        import numpy as _np
-        n_codes = n_groups = 0.0
-        for le in self.store.layers.values():
-            for q in (le.wi_q, le.wo_q):
-                n_codes += float(_np.prod(q.codes.shape))
-                n_groups += float(_np.prod(q.scales.shape))
+        n_codes = float(self.store.code_elements())
+        n_groups = n_codes / self.ecfg.mat.group_size
         return expert_weight_step_bytes(
             n_codes, n_groups, quant_execution=quant_execution,
             dense_itemsize=jnp.dtype(self.cfg.dtype).itemsize)

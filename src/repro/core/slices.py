@@ -4,25 +4,25 @@ One AMAT high-bit code buffer per (layer, expert) weight matrix; the MSB
 and LSB *slices* are views of that buffer (shift / mask), so supporting
 mixed precision costs **zero** extra weight memory — the point of AMAT.
 
-The store serves two consumers:
-
-* the **cache simulator** asks for slice byte sizes and identities
-  (:class:`SliceKey`) to manage the DRAM budget, and
-* the **jitted model** receives stacked ``QuantizedTensor`` expert weights
-  plus a per-expert ``use_lsb`` mask assembled from cache state.
+The codes themselves live in the model's param tree (stacked
+``QuantizedTensor`` leaves under ``experts/{wi_q,wo_q}``), which the
+jitted model reads together with a per-expert ``use_lsb`` mask assembled
+from cache state.  :class:`ExpertSliceStore` holds what the **cache
+simulator** needs and nothing more: slice identities (:class:`SliceKey`)
+and their byte sizes, derived from the per-expert code shapes.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, NamedTuple
+from functools import partial
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.amat import MatConfig, amat_quantize, slice_nbytes
-from repro.quant.groupquant import QuantizedTensor
+from repro.models.moe import moe_param_shapes
 
 
 class SliceKey(NamedTuple):
@@ -31,58 +31,55 @@ class SliceKey(NamedTuple):
     kind: str          # 'msb' | 'lsb'
 
 
-@dataclasses.dataclass
-class LayerExperts:
-    """Stacked AMAT-quantized expert weights for one MoE layer."""
-
-    wi_q: QuantizedTensor          # codes [E, d, F(|2F)]
-    wo_q: QuantizedTensor          # codes [E, F, d]
-
-    @property
-    def n_experts(self) -> int:
-        return self.wi_q.codes.shape[0]
-
-
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class ExpertSliceStore:
-    """All MoE layers' expert weights in AMAT form + slice-size metadata."""
+    """Slice sizes of all MoE layers' AMAT expert weights (no arrays)."""
 
     mat: MatConfig
-    layers: Dict[int, LayerExperts]
-    msb_bytes_per_expert: float = 0.0
-    lsb_bytes_per_expert: float = 0.0
+    layers: Tuple[int, ...]        # flat MoE layer indices
+    n_experts: int
+    wi_shape: Tuple[int, int]      # one expert's wi codes [d, F(|2F)]
+    wo_shape: Tuple[int, int]      # one expert's wo codes [F, d]
+
+    @classmethod
+    def for_config(cls, cfg, mat: MatConfig) -> "ExpertSliceStore":
+        """The store a model config's quantized experts will have — known
+        before any weight exists, so a cache can be sized against it."""
+        shapes = moe_param_shapes(cfg.d_model, cfg.moe)["experts"]
+        n_moe = sum(1 for s in cfg.block_pattern if s.ffn == "moe") \
+            * cfg.n_periods
+        return cls(mat=mat, layers=tuple(range(n_moe)),
+                   n_experts=cfg.moe.n_experts, wi_shape=shapes["wi"][1:],
+                   wo_shape=shapes["wo"][1:])
 
     @classmethod
     def from_float(cls, expert_weights: Dict[int, dict],
                    mat: MatConfig) -> "ExpertSliceStore":
-        """expert_weights: {layer: {'wi': [E,d,F], 'wo': [E,F,d]}} floats."""
-        layers = {}
-        msb_b = lsb_b = 0.0
-        for lidx, w in expert_weights.items():
-            le = LayerExperts(
-                wi_q=amat_quantize(w["wi"], mat),
-                wo_q=amat_quantize(w["wo"], mat),
-            )
-            layers[lidx] = le
-            msb_b = sum(
-                slice_nbytes(q.codes.shape[1:], mat.high_bits,
-                             mat.group_size, which="msb", shift=mat.shift)
-                for q in (le.wi_q, le.wo_q))
-            lsb_b = sum(
-                slice_nbytes(q.codes.shape[1:], mat.high_bits,
-                             mat.group_size, which="lsb", shift=mat.shift)
-                for q in (le.wi_q, le.wo_q))
-        return cls(mat=mat, layers=layers,
-                   msb_bytes_per_expert=msb_b, lsb_bytes_per_expert=lsb_b)
+        """expert_weights: {layer: {'wi': [E,d,F], 'wo': [E,F,d]}}."""
+        first = expert_weights[min(expert_weights)]
+        return cls(mat=mat, layers=tuple(sorted(expert_weights)),
+                   n_experts=int(first["wi"].shape[0]),
+                   wi_shape=tuple(first["wi"].shape[1:]),
+                   wo_shape=tuple(first["wo"].shape[1:]))
 
     # ------------------------------------------------------------ metadata
+    def _slice_total(self, which: str) -> float:
+        m = self.mat
+        return sum(slice_nbytes(s, m.high_bits, m.group_size, which=which,
+                                shift=m.shift)
+                   for s in (self.wi_shape, self.wo_shape))
+
+    @property
+    def msb_bytes_per_expert(self) -> float:
+        return self._slice_total("msb")
+
+    @property
+    def lsb_bytes_per_expert(self) -> float:
+        return self._slice_total("lsb")
+
     @property
     def n_layers(self) -> int:
         return len(self.layers)
-
-    @property
-    def n_experts(self) -> int:
-        return next(iter(self.layers.values())).n_experts
 
     def slice_bytes(self, key: SliceKey) -> float:
         return (self.msb_bytes_per_expert if key.kind == "msb"
@@ -94,38 +91,37 @@ class ExpertSliceStore:
     def total_bytes(self) -> float:
         return self.highbit_expert_bytes() * self.n_layers * self.n_experts
 
+    def code_elements(self) -> int:
+        """Expert weight codes over all layers (one byte each)."""
+        per_expert = sum(a * b for a, b in (self.wi_shape, self.wo_shape))
+        return per_expert * self.n_experts * self.n_layers
+
     def all_keys(self):
         for lidx in self.layers:
             for e in range(self.n_experts):
                 yield SliceKey(lidx, e, "msb")
                 yield SliceKey(lidx, e, "lsb")
 
-    # ------------------------------------------------------- compute views
-    def layer_weights(self, layer: int) -> LayerExperts:
-        return self.layers[layer]
 
-    def use_lsb_mask(self, layer: int, resident_lsb: np.ndarray) -> jax.Array:
-        """Build the jit-input mask from the cache's LSB residency row."""
-        return jnp.asarray(resident_lsb, bool)
+@partial(jax.jit, static_argnames=("mat",))
+def _quantize_periods(w: jax.Array, mat: MatConfig):
+    """AMAT-quantize ``[n_periods, E, K, N]`` one period at a time, so the
+    f32 transient is one layer's worth, not the whole stack's."""
+    return jax.lax.map(
+        lambda wp: amat_quantize(wp.astype(jnp.float32), mat), w)
 
 
-def quantize_moe_params(params: dict, cfg, mat: MatConfig, *,
-                        quant_execution: bool = False):
+def quantize_moe_params(params: dict, cfg, mat: MatConfig):
     """Replace float expert weights in a model param tree by AMAT tensors.
 
-    Returns (new_params, store).  The param tree keeps QuantizedTensor
-    leaves (a registered pytree) under ``experts/{wi_q,wo_q}``; the store
-    indexes the same tensors by *flat layer index* for the cache sim.
-
-    ``quant_execution``: additionally store the ``wo`` codes transposed
-    to the output-major ``[..., d_model, d_ff]`` layout under
-    ``experts/wo_codes_t`` — the layout the transposed batched-expert
-    kernel consumes (so the hot path never transposes at step time).
+    Returns (new_params, store, layer_map).  The param tree keeps
+    QuantizedTensor leaves (a registered pytree) under
+    ``experts/{wi_q,wo_q}``; the store gives the cache simulator their
+    slice sizes by *flat layer index*; ``layer_map`` maps
+    (pattern position, period) to that index.
     """
     pattern = cfg.block_pattern
     new_blocks = dict(params["blocks"])
-    expert_weights: Dict[int, dict] = {}
-    store_layers: Dict[int, LayerExperts] = {}
 
     flat_idx = 0
     layer_map = {}   # (pos, period) -> flat moe layer index
@@ -135,45 +131,17 @@ def quantize_moe_params(params: dict, cfg, mat: MatConfig, *,
                 layer_map[(i, period)] = flat_idx
                 flat_idx += 1
 
-    msb_b = lsb_b = 0.0
     for i, spec in enumerate(pattern):
         if spec.ffn != "moe":
             continue
         blk = dict(new_blocks[f"pos{i}"])
         experts = blk["moe"]["experts"]
-        wi = experts["wi"].astype(jnp.float32)   # [n_periods, E, d, F]
-        wo = experts["wo"].astype(jnp.float32)
-        wi_q = amat_quantize(wi, mat)
-        wo_q = amat_quantize(wo, mat)
         moe_p = dict(blk["moe"])
-        moe_p["experts"] = {"wi_q": wi_q, "wo_q": wo_q}
-        if quant_execution:
-            moe_p["experts"]["wo_codes_t"] = jnp.swapaxes(
-                wo_q.codes, -1, -2)
+        moe_p["experts"] = {"wi_q": _quantize_periods(experts["wi"], mat),
+                            "wo_q": _quantize_periods(experts["wo"], mat)}
         blk["moe"] = moe_p
         new_blocks[f"pos{i}"] = blk
-        for period in range(cfg.n_periods):
-            lidx = layer_map[(i, period)]
-            le = LayerExperts(
-                wi_q=_index_qt(wi_q, period), wo_q=_index_qt(wo_q, period))
-            store_layers[lidx] = le
-            msb_b = sum(
-                slice_nbytes(q.codes.shape[1:], mat.high_bits,
-                             mat.group_size, which="msb", shift=mat.shift)
-                for q in (le.wi_q, le.wo_q))
-            lsb_b = sum(
-                slice_nbytes(q.codes.shape[1:], mat.high_bits,
-                             mat.group_size, which="lsb", shift=mat.shift)
-                for q in (le.wi_q, le.wo_q))
 
     new_params = dict(params)
     new_params["blocks"] = new_blocks
-    store = ExpertSliceStore(
-        mat=mat, layers=store_layers,
-        msb_bytes_per_expert=msb_b, lsb_bytes_per_expert=lsb_b)
-    return new_params, store, layer_map
-
-
-def _index_qt(qt: QuantizedTensor, i: int) -> QuantizedTensor:
-    return QuantizedTensor(qt.codes[i], qt.scales[i], qt.zero_points[i],
-                           qt.bits, qt.group_size, qt.asymmetric)
+    return new_params, ExpertSliceStore.for_config(cfg, mat), layer_map

@@ -163,7 +163,7 @@ def init_last_layer(cache: SliceCache, store: ExpertSliceStore,
                     *_args, **_kw) -> None:
     """Keep only the last prefill layer's experts (naive leftover state)."""
     cache.clear()
-    last = max(store.layers.keys())
+    last = max(store.layers)
     for e in range(store.n_experts):
         for kind in ("msb", "lsb"):
             key = SliceKey(last, e, kind)

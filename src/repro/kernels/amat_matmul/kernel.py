@@ -1,32 +1,37 @@
-"""Pallas TPU kernels: fused AMAT group-dequant + matmul.
+"""Pallas TPU kernel: fused AMAT group-dequant + matmul.
 
 The paper's XPU dequantizes bit-sliced experts in fixed-function hardware
 in front of the systolic array.  The TPU-native equivalent fuses the
-G32 asymmetric dequant into the matmul's K-loop at VMEM-tile granularity:
-a ``(bk, bn)`` uint8 code tile is dequantized in VREGs (subtract zp,
-scale — and for the MSB-only path, a right-shift on code and zp first)
-and immediately fed to the MXU, so the f32 weight tile never exists in
-HBM.  Grid: ``(M/bm, N/bn, K/bk)`` with K innermost, accumulating into
-the output tile (revisited across the K dimension).
+G32 asymmetric dequant into the matmul at VMEM-tile granularity: a
+``(K, bn)`` uint8 code tile is dequantized in VREGs (subtract zp, scale —
+and for the MSB-only path, a right-shift on code and zp first) and
+immediately fed to the MXU, so the dense weight tile never exists in HBM.
 
-Three entry points (see docs/kernels.md for the full grid/BlockSpec map):
+:func:`amat_batched_matmul_pallas` is batched over an expert axis
+(``[E, K, N]`` codes) with **per-expert** precision selection: the
+``use_lsb`` vector rides in via scalar prefetch
+(:class:`pltpu.PrefetchScalarGridSpec`), so expert ``e`` flips between
+the MSB+LSB and the MSB-only dequant constants branch-free.  This is the
+quantized-execution path of the expert FFN; one weight matrix at a
+static precision is the case E=1 (``ops.amat_matmul``).
 
-* :func:`amat_matmul_pallas` — single weight matrix, static precision
-  selection (``mode='high'|'low'``).  Microbenchmark / ablation kernel.
-* :func:`amat_batched_matmul_pallas` — batched over an expert axis
-  (``[E, K, N]`` codes) with **per-expert** precision selection: the
-  ``use_lsb`` vector rides in via scalar prefetch
-  (:class:`pltpu.PrefetchScalarGridSpec`), so expert ``e`` flips between
-  the MSB+LSB and the MSB-only dequant constants branch-free inside the
-  K loop.  This is the quantized-execution path of the expert FFN.
-* :func:`amat_batched_matmul_t_pallas` — the transposed variant for the
-  ``wo`` projection: codes stored output-major (``[E, N, K]``), the
-  tile is transposed in VREGs after the DMA so group metadata stays in
-  the canonical ``[E, K//G, N]`` layout.
+Tiling (what the TPU compiler accepts, see docs/kernels.md):
 
-Tiling constraints: ``bk % group_size == 0`` so each K-tile covers whole
-quantization groups; bm/bn multiples of (8, 128) keep the MXU aligned.
-All kernels accept ``interpret=True`` so CPU CI executes the same body.
+* Each block spans the whole contraction axis K, so the group metadata
+  block ``(K//G, bn)`` is the full second-minor dimension — legal for
+  any group count (a K-tiled ``(bk//G, bn)`` block is not, unless
+  ``bk//G`` is a multiple of the dtype's sublane tile).  Expert widths
+  keep the ``(K, bn)`` tile small: 2048 x 256 uint8 is 512 KB.
+* Grid ``(E, N/bn, M/bm)`` with M innermost: the code tile's block index
+  does not change across M tiles, so it is DMA'd once per (e, j).
+* uint8 codes and zero-points are widened to int32 before any shift or
+  float conversion (Mosaic has no uint8 -> float32 cast).
+* The weight tile is cast to the activation dtype before the dot, as the
+  dense-dequant path casts its weights (bf16 operands, f32 accumulation
+  for a bf16 model).
+
+``interpret=True`` runs the same body on CPU (the tests); on a TPU the
+wrappers in ``ops.py`` never set it.
 """
 
 from __future__ import annotations
@@ -39,146 +44,46 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _amat_matmul_kernel(x_ref, c_ref, s_ref, z_ref, o_ref, acc_ref, *,
-                        group_size: int, shift: int, low: bool,
-                        n_k: int):
-    k = pl.program_id(2)
+def _dequant_tile(codes, s, z, hi, *, group_size: int, shift: int, dtype):
+    """Dequantize a [K, bn] code tile with runtime precision.
 
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...].astype(jnp.float32)              # [bm, bk]
-    codes = c_ref[...]                              # [bk, bn] uint8
-    s = s_ref[...].astype(jnp.float32)              # [bk//G, bn]
-    z = z_ref[...].astype(jnp.float32)              # [bk//G, bn]
-
-    bk, bn = codes.shape
-    g = bk // group_size
-    c = codes.reshape(g, group_size, bn).astype(jnp.float32)
-    zb = z.reshape(g, 1, bn)
-    sb = s.reshape(g, 1, bn)
-    if low and shift > 0:
-        c = jnp.floor(c * (0.5 ** shift))
-        zb = jnp.floor(zb * (0.5 ** shift))
-        sb = sb * (2.0 ** shift)
-    w = ((c - zb) * sb).reshape(bk, bn)             # dequant in VREGs
-
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(k == n_k - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def amat_matmul_pallas(x, codes, scales, zps, *, group_size: int = 32,
-                       shift: int = 0, mode: str = "high",
-                       bm: int = 128, bn: int = 128, bk: int = 128,
-                       interpret: bool = False):
-    """x: [M, K]; codes: [K, N] uint8; scales/zps: [K//G, N] -> [M, N] f32."""
-    M, K = x.shape
-    K2, N = codes.shape
-    assert K == K2 and K % group_size == 0
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert bk % group_size == 0, "K tile must cover whole groups"
-    assert N % bn == 0 and K % bk == 0, \
-        f"pad N/K to block multiples: {(N, K)} vs {(bn, bk)}"
-    # Decode batches are rarely multiples of bm: pad M internally and
-    # slice the result (padded rows hit zeroed x, contributing nothing).
-    m_pad = (-M) % bm
-    if m_pad:
-        x = jnp.pad(x, ((0, m_pad), (0, 0)))
-    Mp = M + m_pad
-    n_k = K // bk
-    gs_per_bk = bk // group_size
-
-    kernel = functools.partial(
-        _amat_matmul_kernel, group_size=group_size, shift=shift,
-        low=(mode == "low"), n_k=n_k)
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(Mp // bm, N // bn, n_k),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((gs_per_bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((gs_per_bk, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, N), jnp.float32),
-        # f32 accumulator tile in VMEM, revisited across the K grid dim
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, codes, scales, zps)
-    return out[:M] if m_pad else out
-
-
-# --------------------------------------------------------------------------
-# Batched-expert kernels (the quantized-execution path of the expert FFN)
-# --------------------------------------------------------------------------
-def _dequant_tile(codes, s, z, use_lsb_e, *, group_size: int, shift: int):
-    """Dequantize a [bk, bn] code tile in VREGs with runtime precision.
-
-    ``use_lsb_e`` is a scalar bool (this expert's precision): True keeps
-    the full high-bit code; False applies the AMAT truncation (shift on
-    code *and* zero-point, rescale) — both paths cost one FMA since the
-    select is on the dequant constants, not on the result.
+    ``hi`` is a scalar bool (this expert's precision): True keeps the
+    full high-bit code; False applies the AMAT truncation (shift on code
+    *and* zero-point, rescale by ``2**shift``).  The select is on two
+    scalars, so both paths cost the same vector work.
     """
-    bk, bn = codes.shape
-    g = bk // group_size
-    c = codes.reshape(g, group_size, bn).astype(jnp.float32)
-    zb = z.astype(jnp.float32).reshape(g, 1, bn)
-    sb = s.astype(jnp.float32).reshape(g, 1, bn)
-    if shift > 0:
-        inv = 0.5 ** shift
-        c = jnp.where(use_lsb_e, c, jnp.floor(c * inv))
-        zb = jnp.where(use_lsb_e, zb, jnp.floor(zb * inv))
-        sb = jnp.where(use_lsb_e, sb, sb * (2.0 ** shift))
-    return ((c - zb) * sb).reshape(bk, bn)
+    K, bn = codes.shape
+    g = K // group_size
+    sh = jnp.where(hi, 0, shift).astype(jnp.int32)
+    mult = jnp.where(hi, 1.0, float(2 ** shift)).astype(jnp.float32)
+    c = (codes.astype(jnp.int32) >> sh).astype(jnp.float32)
+    zf = (z.astype(jnp.int32) >> sh).astype(jnp.float32)
+    sf = s.astype(jnp.float32) * mult
+    w = (c.reshape(g, group_size, bn) - zf[:, None, :]) * sf[:, None, :]
+    return w.reshape(K, bn).astype(dtype)
 
 
-def _amat_batched_kernel(u_ref, x_ref, c_ref, s_ref, z_ref, o_ref,
-                         acc_ref, *, group_size: int, shift: int,
-                         n_k: int, transposed: bool):
-    e = pl.program_id(0)
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[0].astype(jnp.float32)                # [bm, bk]
-    codes = c_ref[0]                                # [bk, bn] | [bn, bk]
-    if transposed:
-        # output-major wo layout: transpose the code tile in VREGs so the
-        # dequant + dot share the K-major path (metadata is K-major).
-        codes = codes.T
-    hi = u_ref[e] > 0                               # scalar-prefetched flag
-    w = _dequant_tile(codes, s_ref[0], z_ref[0], hi,
-                      group_size=group_size, shift=shift)
-
-    acc_ref[...] += jax.lax.dot_general(
+def _amat_batched_kernel(u_ref, x_ref, c_ref, s_ref, z_ref, o_ref, *,
+                         group_size: int, shift: int):
+    hi = u_ref[pl.program_id(0)] > 0                # scalar-prefetched flag
+    x = x_ref[0]                                    # [bm, K]
+    w = _dequant_tile(c_ref[0], s_ref[0], z_ref[0], hi,
+                      group_size=group_size, shift=shift, dtype=x.dtype)
+    o_ref[0] = jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(k == n_k - 1)
-    def _flush():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def amat_batched_matmul_pallas(x, codes, scales, zps, use_lsb, *,
                                group_size: int = 32, shift: int = 4,
-                               bm: int = 128, bn: int = 128, bk: int = 128,
-                               transposed: bool = False,
+                               bm: int = 128, bn: int = 256,
                                interpret: bool = False):
     """Per-expert fused dequant-matmul on packed AMAT codes.
 
-    x: [E, M, K]; codes: [E, K, N] (or [E, N, K] when ``transposed``);
-    scales/zps: [E, K//G, N]; use_lsb: [E] (bool/int) — expert ``e``
-    computes at high precision iff ``use_lsb[e]``.  Returns [E, M, N] f32.
+    x: [E, M, K]; codes: [E, K, N] uint8; scales/zps: [E, K//G, N];
+    use_lsb: [E] (bool/int) — expert ``e`` computes at high precision iff
+    ``use_lsb[e]``.  Returns [E, M, N] f32.  ``N`` must be a multiple of
+    ``bn`` (or ``bn >= N``); ``M`` is padded to a multiple of ``bm``.
 
     ``use_lsb`` travels via scalar prefetch: it is resident in SMEM
     before the grid starts, so per-expert precision selection costs no
@@ -186,58 +91,40 @@ def amat_batched_matmul_pallas(x, codes, scales, zps, use_lsb, *,
     decisions become per-expert dequant shifts inside one kernel launch.
     """
     E, M, K = x.shape
-    N = codes.shape[1] if transposed else codes.shape[2]
-    assert codes.shape == ((E, N, K) if transposed else (E, K, N))
+    N = codes.shape[2]
+    assert codes.shape == (E, K, N), (codes.shape, x.shape)
     assert K % group_size == 0
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert bk % group_size == 0, "K tile must cover whole groups"
-    assert N % bn == 0 and K % bk == 0, \
-        f"pad N/K to block multiples: {(N, K)} vs {(bn, bk)}"
+    assert scales.shape == zps.shape == (E, K // group_size, N)
+    bm, bn = min(bm, M), min(bn, N)
+    assert N % bn == 0, f"pad N to a multiple of bn: {N} vs {bn}"
     m_pad = (-M) % bm
     if m_pad:
+        # Padded rows hit zeroed x, contributing nothing.
         x = jnp.pad(x, ((0, 0), (0, m_pad), (0, 0)))
     Mp = M + m_pad
-    n_k = K // bk
-    g_bk = bk // group_size
+    g = K // group_size
     u = use_lsb.astype(jnp.int32)
 
-    kernel = functools.partial(
-        _amat_batched_kernel, group_size=group_size, shift=shift,
-        n_k=n_k, transposed=transposed)
-    code_spec = (
-        pl.BlockSpec((1, bn, bk), lambda e, i, j, k, u_ref: (e, j, k))
-        if transposed else
-        pl.BlockSpec((1, bk, bn), lambda e, i, j, k, u_ref: (e, k, j)))
-
+    kernel = functools.partial(_amat_batched_kernel, group_size=group_size,
+                               shift=shift)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(E, Mp // bm, N // bn, n_k),
+        grid=(E, N // bn, Mp // bm),
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda e, i, j, k, u_ref: (e, i, k)),
-            code_spec,
-            pl.BlockSpec((1, g_bk, bn), lambda e, i, j, k, u_ref: (e, k, j)),
-            pl.BlockSpec((1, g_bk, bn), lambda e, i, j, k, u_ref: (e, k, j)),
+            pl.BlockSpec((1, bm, K), lambda e, j, i, u_ref: (e, i, 0)),
+            pl.BlockSpec((1, K, bn), lambda e, j, i, u_ref: (e, 0, j)),
+            pl.BlockSpec((1, g, bn), lambda e, j, i, u_ref: (e, 0, j)),
+            pl.BlockSpec((1, g, bn), lambda e, j, i, u_ref: (e, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn),
-                               lambda e, i, j, k, u_ref: (e, i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+                               lambda e, j, i, u_ref: (e, i, j)),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, Mp, N), jnp.float32),
+        name="amat_expert_matmul",
         interpret=interpret,
     )(u, x, codes, scales, zps)
     return out[:, :M] if m_pad else out
 
-
-def amat_batched_matmul_t_pallas(x, codes_t, scales, zps, use_lsb, **kw):
-    """Transposed-weight variant: codes_t [E, N, K], metadata [E, K//G, N].
-
-    Used for the ``wo`` projection when its codes are stored output-major
-    (``[E, d_model, d_ff]``) so both expert weight matrices share the
-    d_model-minor HBM layout; the code tile is transposed in VREGs after
-    the DMA — group metadata never changes layout.
-    """
-    return amat_batched_matmul_pallas(x, codes_t, scales, zps, use_lsb,
-                                      transposed=True, **kw)

@@ -1,11 +1,11 @@
-"""Jit'd public wrappers for the fused AMAT dequant-matmul kernels.
+"""Jit'd public wrappers for the fused AMAT dequant-matmul kernel.
 
 Handle padding to block multiples, backend detection (interpret=True on
 CPU — executes the kernel body in Python for correctness validation; on
 TPU the same BlockSpecs drive real VMEM tiling) and the QuantizedTensor
-calling convention.  ``amat_expert_matmul`` / ``amat_expert_matmul_t``
-are the quantized-execution entry points the MoE layer calls on the
-``[E, C, d]`` dispatch buffer (see docs/kernels.md).
+calling convention.  ``amat_expert_matmul`` is the quantized-execution
+entry point the MoE layer calls on the ``[E, C, d]`` dispatch buffer
+(see docs/kernels.md).
 """
 
 from __future__ import annotations
@@ -15,8 +15,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.amat_matmul.kernel import (amat_batched_matmul_pallas,
-                                              amat_matmul_pallas)
+from repro.kernels.amat_matmul.kernel import amat_batched_matmul_pallas
 from repro.quant.groupquant import QuantizedTensor
 
 
@@ -29,69 +28,27 @@ def _pad_to(x, m, axis):
     return jnp.pad(x, widths)
 
 
-@partial(jax.jit, static_argnames=("group_size", "shift", "mode",
-                                   "bm", "bn", "bk", "interpret"))
-def amat_matmul(x, codes, scales, zps, *, group_size: int = 32,
-                shift: int = 0, mode: str = "high",
-                bm: int = 128, bn: int = 128, bk: int = 128,
-                interpret: bool | None = None):
-    """x [M, K] @ dequant(codes [K, N]) -> [M, N] f32."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    M, K = x.shape
-    N = codes.shape[1]
-    bm_, bn_, bk_ = min(bm, M), min(bn, N), min(bk, K)
-    bk_ = max(group_size, bk_ - bk_ % group_size)
-    # pad to block multiples
-    xp = _pad_to(_pad_to(x, bm_, 0), bk_, 1)
-    cp = _pad_to(_pad_to(codes, bk_, 0), bn_, 1)
-    sp = _pad_to(_pad_to(scales, bk_ // group_size, 0), bn_, 1)
-    zp_ = _pad_to(_pad_to(zps, bk_ // group_size, 0), bn_, 1)
-    out = amat_matmul_pallas(
-        xp, cp, sp, zp_, group_size=group_size, shift=shift, mode=mode,
-        bm=bm_, bn=bn_, bk=bk_, interpret=interpret)
-    return out[:M, :N]
-
-
-def amat_matmul_qt(x, qt: QuantizedTensor, *, shift: int = 0,
-                   mode: str = "high", **kw):
-    assert qt.asymmetric, "AMAT kernel expects asymmetric group quant"
-    return amat_matmul(x, qt.codes, qt.scales,
-                       qt.zero_points, group_size=qt.group_size,
-                       shift=shift, mode=mode, **kw)
-
-
-@partial(jax.jit, static_argnames=("group_size", "shift", "transposed",
-                                   "bm", "bn", "bk", "interpret"))
+@partial(jax.jit, static_argnames=("group_size", "shift", "bm", "bn",
+                                   "interpret"))
 def amat_expert_matmul(x, codes, scales, zps, use_lsb, *,
                        group_size: int = 32, shift: int = 4,
-                       transposed: bool = False,
-                       bm: int = 128, bn: int = 128, bk: int = 128,
+                       bm: int = 128, bn: int = 256,
                        interpret: bool | None = None):
     """[E, M, K] @ per-expert-dequant([E, K, N] codes) -> [E, M, N] f32.
 
     ``use_lsb`` [E] selects MSB+LSB (high-bit) vs MSB-only dequant per
-    expert inside the kernel.  ``transposed=True`` reads output-major
-    codes ([E, N, K]) — the ``wo`` projection layout.  M is padded
-    in-kernel; K/N are padded here (zero scales null the pad region).
+    expert inside the kernel.  M is padded in-kernel; N is padded here
+    to a multiple of ``bn`` (zero scales null the pad region).  Expert
+    widths are multiples of 128, so the served path never pads.
     """
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    E, M, K = x.shape
-    N = codes.shape[1] if transposed else codes.shape[2]
-    bn_, bk_ = min(bn, N), min(bk, K)
-    bk_ = max(group_size, bk_ - bk_ % group_size)
-    xp = _pad_to(x, bk_, 2)
-    if transposed:
-        cp = _pad_to(_pad_to(codes, bn_, 1), bk_, 2)
-    else:
-        cp = _pad_to(_pad_to(codes, bk_, 1), bn_, 2)
-    sp = _pad_to(_pad_to(scales, bk_ // group_size, 1), bn_, 2)
-    zp_ = _pad_to(_pad_to(zps, bk_ // group_size, 1), bn_, 2)
+    N = codes.shape[2]
+    bn_ = min(bn, N)
     out = amat_batched_matmul_pallas(
-        xp, cp, sp, zp_, use_lsb, group_size=group_size, shift=shift,
-        bm=min(bm, M), bn=bn_, bk=bk_, transposed=transposed,
-        interpret=interpret)
+        x, _pad_to(codes, bn_, 2), _pad_to(scales, bn_, 2),
+        _pad_to(zps, bn_, 2), use_lsb, group_size=group_size, shift=shift,
+        bm=bm, bn=bn_, interpret=interpret)
     return out[:, :, :N]
 
 
@@ -104,9 +61,19 @@ def amat_expert_matmul_qt(x, qt: QuantizedTensor, use_lsb, *, shift: int,
                               shift=shift, **kw)
 
 
-def amat_expert_matmul_t(x, codes_t, scales, zps, use_lsb, *, shift: int,
-                         group_size: int = 32, **kw):
-    """Transposed-weight entry point: codes_t [E, N, K] output-major."""
-    return amat_expert_matmul(x, codes_t, scales, zps, use_lsb,
-                              group_size=group_size, shift=shift,
-                              transposed=True, **kw)
+def amat_matmul(x, codes, scales, zps, *, group_size: int = 32,
+                shift: int = 0, mode: str = "high", **kw):
+    """x [M, K] @ dequant(codes [K, N]) -> [M, N] f32 at a static
+    precision: the expert kernel with one expert."""
+    hi = jnp.asarray([mode == "high"])
+    return amat_expert_matmul(x[None], codes[None], scales[None],
+                              zps[None], hi, group_size=group_size,
+                              shift=shift, **kw)[0]
+
+
+def amat_matmul_qt(x, qt: QuantizedTensor, *, shift: int = 0,
+                   mode: str = "high", **kw):
+    assert qt.asymmetric, "AMAT kernel expects asymmetric group quant"
+    return amat_matmul(x, qt.codes, qt.scales, qt.zero_points,
+                       group_size=qt.group_size, shift=shift, mode=mode,
+                       **kw)

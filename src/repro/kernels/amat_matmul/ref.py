@@ -48,11 +48,3 @@ def amat_batched_matmul_ref(x, codes, scales, zps, use_lsb, *,
     w = _dequant_mixed_ref(codes, scales, zps, use_lsb,
                            group_size=group_size, shift=shift)
     return jnp.einsum("emk,ekn->emn", x.astype(jnp.float32), w)
-
-
-def amat_batched_matmul_t_ref(x, codes_t, scales, zps, use_lsb, *,
-                              group_size: int = 32, shift: int = 4):
-    """Transposed-weight oracle: codes_t [E, N, K], metadata [E, K//G, N]."""
-    codes = jnp.swapaxes(codes_t, -1, -2)
-    return amat_batched_matmul_ref(x, codes, scales, zps, use_lsb,
-                                   group_size=group_size, shift=shift)

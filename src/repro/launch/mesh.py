@@ -20,25 +20,17 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n: int) -> dict:
-    # jax.sharding.AxisType landed after 0.4.x; older jax defaults every
-    # axis to Auto, which is exactly what we want — so only pass the
-    # kwarg when the enum exists.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """1-device mesh with the production axis names (tests / examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_mesh_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple:

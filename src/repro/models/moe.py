@@ -184,31 +184,23 @@ def _expert_ffn(xe: jax.Array, wi: jax.Array, wo: jax.Array,
 
 def _expert_ffn_quant(xe: jax.Array, wi_q: QuantizedTensor,
                       wo_q: QuantizedTensor,
-                      wo_codes_t: Optional[jax.Array],
                       use_lsb: Optional[jax.Array], shift: int,
                       mlp_type: str) -> jax.Array:
     """Expert FFN computed *directly on packed AMAT codes* (no dense
     weight tensor is ever materialized — the paper's in-front-of-compute
-    dequantization, here fused into the Pallas matmul's K loop).
+    dequantization, here fused into the Pallas matmul).
 
     ``use_lsb`` [E] selects the per-expert dequant shift inside the
-    kernel; ``wo_codes_t`` is the pre-transposed (output-major,
-    ``[E, d, F]``) wo code buffer — when absent the canonical ``[E, F,
-    d]`` codes are used with the K-major kernel.
+    kernel.  Both projections read their codes in the canonical K-major
+    layout.
     """
-    from repro.kernels.amat_matmul.ops import (amat_expert_matmul_qt,
-                                               amat_expert_matmul_t)
+    from repro.kernels.amat_matmul.ops import amat_expert_matmul_qt
 
     ul = use_lsb if use_lsb is not None \
         else jnp.ones((xe.shape[0],), bool)
     h = amat_expert_matmul_qt(xe, wi_q, ul, shift=shift).astype(xe.dtype)
     h = _ffn_activation(h, mlp_type, xe.dtype)
-    if wo_codes_t is not None:
-        y = amat_expert_matmul_t(h, wo_codes_t, wo_q.scales,
-                                 wo_q.zero_points, ul, shift=shift,
-                                 group_size=wo_q.group_size)
-    else:
-        y = amat_expert_matmul_qt(h, wo_q, ul, shift=shift)
+    y = amat_expert_matmul_qt(h, wo_q, ul, shift=shift)
     return y.astype(xe.dtype)
 
 
@@ -363,9 +355,8 @@ def moe_apply(
         # No dense expert tensor is materialized (and hence no
         # dequant-tile shard_hint workaround is needed — the kernel
         # reads the codes at their native sharding).
-        ye = _expert_ffn_quant(xe, wi_qt, wo_qt,
-                               experts.get("wo_codes_t"), use_lsb,
-                               mat.shift, cfg.mlp_type)
+        ye = _expert_ffn_quant(xe, wi_qt, wo_qt, use_lsb, mat.shift,
+                               cfg.mlp_type)
     elif wi_qt is not None:
         # Dense-dequant reference path: materialize per-expert f32/bf16
         # weights each step (gather-then-dequantize).

@@ -19,7 +19,7 @@ See docs/simulation.md for the schema, fidelity guarantees and knobs.
 
 from repro.sim.trace import (DecodeEvent, PrefillEvent, Trace, TraceMeta,
                              TraceRecorder, engine_meta, traces_equal)
-from repro.sim.replay import (ReplayEngine, ReplayReport, TraceSliceStore,
+from repro.sim.replay import (ReplayEngine, ReplayReport,
                               engine_config_from_meta, replay_trace)
 from repro.sim.synthetic import (SyntheticSpec, phase_shift_trace,
                                  tenant_mix_trace, tenant_phase_trace,
@@ -29,7 +29,7 @@ from repro.sim import autotune
 __all__ = [
     "Trace", "TraceMeta", "TraceRecorder", "PrefillEvent", "DecodeEvent",
     "engine_meta", "traces_equal",
-    "ReplayEngine", "ReplayReport", "TraceSliceStore",
+    "ReplayEngine", "ReplayReport",
     "engine_config_from_meta", "replay_trace",
     "SyntheticSpec", "zipf_trace", "phase_shift_trace",
     "tenant_mix_trace", "tenant_phase_trace", "transition_trace",

@@ -33,61 +33,20 @@ from __future__ import annotations
 import dataclasses
 import time
 from types import SimpleNamespace
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core.amat import MatConfig, slice_nbytes
+from repro.core.amat import MatConfig
 from repro.core.engine import EngineConfig, PersistentEngine, _StepTrace
-from repro.core.slices import SliceKey
+from repro.core.slices import ExpertSliceStore
 from repro.core.warmup import HotnessTracker
 from repro.hw.specs import SYSTEM_PROFILES
 from repro.models.moe import RoutingPolicy
 from repro.sim.trace import Trace, TraceMeta
 
-__all__ = ["TraceSliceStore", "engine_config_from_meta", "ReplayEngine",
-           "ReplayReport", "replay_trace"]
-
-
-class TraceSliceStore:
-    """Byte-size stand-in for :class:`~repro.core.slices.ExpertSliceStore`.
-
-    Rebuilt from trace metadata for *any* AMAT bit plan: slice bytes come
-    from the same :func:`~repro.core.amat.slice_nbytes` on the same
-    per-expert code shapes the live store used, so byte accounting is
-    identical — without holding a single weight.
-    """
-
-    def __init__(self, meta: TraceMeta, mat: MatConfig):
-        self.mat = mat
-        self.n_experts = meta.n_experts
-        # pcw/init_* only need the flat layer keys, not weights
-        self.layers: Dict[int, None] = {
-            l: None for l in range(meta.n_moe_layers)}
-        shapes = (meta.wi_shape, meta.wo_shape)
-        self.msb_bytes_per_expert = sum(
-            slice_nbytes(s, mat.high_bits, mat.group_size,
-                         which="msb", shift=mat.shift) for s in shapes)
-        self.lsb_bytes_per_expert = sum(
-            slice_nbytes(s, mat.high_bits, mat.group_size,
-                         which="lsb", shift=mat.shift) for s in shapes)
-
-    def slice_bytes(self, key: SliceKey) -> float:
-        return (self.msb_bytes_per_expert if key.kind == "msb"
-                else self.lsb_bytes_per_expert)
-
-    def highbit_expert_bytes(self) -> float:
-        return self.msb_bytes_per_expert + self.lsb_bytes_per_expert
-
-    def total_bytes(self) -> float:
-        return self.highbit_expert_bytes() * len(self.layers) \
-            * self.n_experts
-
-    def all_keys(self):
-        for lidx in self.layers:
-            for e in range(self.n_experts):
-                yield SliceKey(lidx, e, "msb")
-                yield SliceKey(lidx, e, "lsb")
+__all__ = ["engine_config_from_meta", "ReplayEngine", "ReplayReport",
+           "replay_trace"]
 
 
 def engine_config_from_meta(meta: TraceMeta, **overrides) -> EngineConfig:
@@ -240,7 +199,13 @@ class ReplayEngine(PersistentEngine):
         self.cfg = SimpleNamespace(name=meta.model, d_model=meta.d_model,
                                    n_periods=meta.n_periods)
         self.ecfg = ecfg
-        self.store = TraceSliceStore(meta, ecfg.mat)
+        # Slice bytes come from the recorded per-expert code shapes under
+        # *this* config's bit plan: the live store's accounting, with no
+        # weights behind it.
+        self.store = ExpertSliceStore(
+            mat=ecfg.mat, layers=tuple(range(meta.n_moe_layers)),
+            n_experts=meta.n_experts, wi_shape=tuple(meta.wi_shape),
+            wo_shape=tuple(meta.wo_shape))
         self.layer_map = meta.layer_map()
         self.moe_positions = list(meta.moe_positions)
         self.n_moe_layers = meta.n_moe_layers

@@ -308,7 +308,6 @@ def traces_equal(a: Trace, b: Trace) -> bool:
 def engine_meta(engine) -> TraceMeta:
     """Build the replay header from a live :class:`PersistentEngine`."""
     ecfg = engine.ecfg
-    first = engine.store.layers[min(engine.store.layers)]
     return TraceMeta(
         model=engine.cfg.name,
         d_model=int(engine.cfg.d_model),
@@ -318,8 +317,8 @@ def engine_meta(engine) -> TraceMeta:
         n_experts=int(engine.n_experts),
         top_k=int(engine.cfg.moe.top_k),
         group_size=int(ecfg.mat.group_size),
-        wi_shape=tuple(int(x) for x in first.wi_q.codes.shape[1:]),
-        wo_shape=tuple(int(x) for x in first.wo_q.codes.shape[1:]),
+        wi_shape=tuple(int(x) for x in engine.store.wi_shape),
+        wo_shape=tuple(int(x) for x in engine.store.wo_shape),
         resident_bytes=float(engine.resident_bytes),
         expert_macs_per_token=int(engine.expert_macs_per_token),
         engine={

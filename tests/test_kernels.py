@@ -10,12 +10,10 @@ pytestmark = pytest.mark.slow
 from hypothesis import given, settings, strategies as st
 
 from repro.core.amat import PAPER_CONFIGS, amat_quantize
-from repro.kernels.amat_matmul.kernel import amat_matmul_pallas
+from repro.kernels.amat_matmul.kernel import amat_batched_matmul_pallas
 from repro.kernels.amat_matmul.ops import (amat_expert_matmul_qt,
-                                           amat_expert_matmul_t,
                                            amat_matmul, amat_matmul_qt)
 from repro.kernels.amat_matmul.ref import (amat_batched_matmul_ref,
-                                           amat_batched_matmul_t_ref,
                                            amat_matmul_ref)
 from repro.kernels.expert_matmul.ops import expert_matmul_qt
 from repro.kernels.expert_matmul.ref import expert_matmul_ref
@@ -54,8 +52,8 @@ class TestAmatMatmul:
         qt = quantize(w, bits=8, group_size=32, asymmetric=True)
         outs = [
             amat_matmul(x, qt.codes, qt.scales, qt.zero_points,
-                        bm=bm, bn=bn, bk=bk)
-            for bm, bn, bk in [(16, 16, 32), (64, 64, 64), (32, 64, 128)]
+                        bm=bm, bn=bn)
+            for bm, bn in [(16, 16), (64, 64), (32, 32)]
         ]
         for o in outs[1:]:
             np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
@@ -78,8 +76,10 @@ class TestAmatMatmul:
             x = jax.random.normal(rng, (M, 64))
             w = jax.random.normal(jax.random.fold_in(rng, M), (64, 128)) * 0.1
             qt = quantize(w, bits=8, group_size=32, asymmetric=True)
-            out = amat_matmul_pallas(x, qt.codes, qt.scales, qt.zero_points,
-                                     bm=128, bn=128, bk=64, interpret=True)
+            out = amat_batched_matmul_pallas(
+                x[None], qt.codes[None], qt.scales[None],
+                qt.zero_points[None], jnp.ones(1, bool), bm=128, bn=128,
+                interpret=True)[0]
             ref = amat_matmul_ref(x, qt.codes, qt.scales, qt.zero_points)
             assert out.shape == (M, 128)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -109,24 +109,21 @@ class TestAmatBatchedMatmul:
                                    atol=1e-4)
 
     @pytest.mark.parametrize("mat", PAPER_CONFIGS, ids=lambda m: m.name)
-    def test_transposed_variant_matches_ref(self, rng, mat):
-        E, M, K, N = 3, 9, 64, 48
+    def test_odd_group_count_matches_ref(self, rng, mat):
+        """K = 11 groups, as in a wo projection of width 1408 (44 groups):
+        the metadata block spans the whole group axis, whatever its
+        count."""
+        E, M, K, N = 3, 9, 11 * 32, 48
         x = jax.random.normal(rng, (E, M, K))
         w = jax.random.normal(jax.random.fold_in(rng, 1), (E, K, N)) * 0.1
         qt = amat_quantize(w, mat)
-        ct = jnp.swapaxes(qt.codes, -1, -2)       # output-major wo layout
         ul = jnp.arange(E) % 2 == 1
-        out = amat_expert_matmul_t(x, ct, qt.scales, qt.zero_points, ul,
-                                   shift=mat.shift,
-                                   group_size=mat.group_size)
-        ref = amat_batched_matmul_t_ref(x, ct, qt.scales, qt.zero_points,
-                                        ul, group_size=mat.group_size,
-                                        shift=mat.shift)
+        out = amat_expert_matmul_qt(x, qt, ul, shift=mat.shift)
+        ref = amat_batched_matmul_ref(x, qt.codes, qt.scales,
+                                      qt.zero_points, ul,
+                                      group_size=mat.group_size,
+                                      shift=mat.shift)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-4)
-        # and the transposed layout agrees with the K-major kernel
-        canon = amat_expert_matmul_qt(x, qt, ul, shift=mat.shift)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(canon),
                                    atol=1e-4)
 
     def test_use_lsb_extremes_match_static_modes(self, rng):
@@ -170,10 +167,8 @@ class TestAmatBatchedMatmul:
         w = jax.random.normal(jax.random.fold_in(rng, 1), (E, K, N)) * 0.1
         qt = quantize(w, bits=8, group_size=32, asymmetric=True)
         ul = jnp.array([True, False])
-        outs = [amat_expert_matmul_qt(x, qt, ul, shift=4, bm=bm, bn=bn,
-                                      bk=bk)
-                for bm, bn, bk in [(16, 16, 32), (32, 64, 64),
-                                   (128, 128, 128)]]
+        outs = [amat_expert_matmul_qt(x, qt, ul, shift=4, bm=bm, bn=bn)
+                for bm, bn in [(16, 16), (32, 64), (128, 128)]]
         for o in outs[1:]:
             np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                        atol=1e-4)

@@ -123,30 +123,6 @@ class TestMoEApply:
             np.testing.assert_allclose(np.asarray(y_kern),
                                        np.asarray(y_dense), atol=1e-4)
 
-    def test_quant_execution_uses_transposed_wo_codes(self, rng):
-        """A pre-transposed wo code buffer (engine layout) must give the
-        same result as the canonical layout."""
-        params = _params(rng, D, CFG)
-        x = jax.random.normal(rng, (16, D)) * 0.5
-        _, aux_f = moe_apply(params, x, CFG)
-        go = (aux_f["gates"], aux_f["ids"])
-        wo_q = amat_quantize(params["experts"]["wo"], MAT84)
-        base = {
-            "wi_q": amat_quantize(params["experts"]["wi"], MAT84),
-            "wo_q": wo_q,
-        }
-        qp = dict(params)
-        qp["experts"] = base
-        y_canon, _ = moe_apply(qp, x, CFG, mat=MAT84, gate_override=go,
-                               quant_execution=True)
-        qp_t = dict(params)
-        qp_t["experts"] = dict(base,
-                               wo_codes_t=jnp.swapaxes(wo_q.codes, -1, -2))
-        y_t, _ = moe_apply(qp_t, x, CFG, mat=MAT84, gate_override=go,
-                           quant_execution=True)
-        np.testing.assert_allclose(np.asarray(y_t), np.asarray(y_canon),
-                                   atol=1e-4)
-
     def test_use_lsb_selects_precision(self, rng):
         params = _params(rng, D, CFG)
         x = jax.random.normal(rng, (16, D)) * 0.5

@@ -184,16 +184,16 @@ class TestQuantExecutionParity:
         quant = eng.expert_weight_bytes_per_step(quant_execution=True)
         assert quant * 2 <= dense, (quant, dense)
 
-    def test_qparams_carry_transposed_wo_codes(self, setup):
-        """quant_execution engines pre-transpose wo codes at quantize
-        time so the hot path never transposes at step time."""
+    def test_engine_holds_one_copy_of_expert_weights(self, setup):
+        """The packed codes in the param tree are the only expert
+        weights: no float copy, no transposed copy, no store copy."""
         *_, eng = self._run(setup, True)
+        assert not hasattr(eng, "float_params")
         for blk in eng.qparams["blocks"].values():
             if "moe" in blk:
-                e = blk["moe"]["experts"]
-                assert "wo_codes_t" in e
-                P, E, F, d = e["wo_q"].codes.shape
-                assert e["wo_codes_t"].shape == (P, E, d, F)
+                assert set(blk["moe"]["experts"]) == {"wi_q", "wo_q"}
+        leaves = jax.tree_util.tree_leaves(dataclasses.astuple(eng.store))
+        assert not any(isinstance(x, jax.Array) for x in leaves)
 
 
 @pytest.mark.slow

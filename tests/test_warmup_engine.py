@@ -45,6 +45,24 @@ class TestStore:
         assert small_store.total_bytes() == pytest.approx(
             small_store.highbit_expert_bytes() * 3 * 8)
 
+    def test_for_config_matches_quantized_params(self):
+        """The store sized from the config alone agrees with the codes
+        quantization produces, so a cache can be sized before weights
+        exist."""
+        from repro.core.slices import quantize_moe_params
+
+        cfg = get_config("qwen15-moe-repro")
+        mat = MatConfig(8, 4)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        qparams, store, layer_map = quantize_moe_params(params, cfg, mat)
+        assert store == ExpertSliceStore.for_config(cfg, mat)
+        assert store.layers == tuple(sorted(layer_map.values()))
+        e = qparams["blocks"]["pos0"]["moe"]["experts"]
+        assert e["wi_q"].codes.shape[2:] == store.wi_shape
+        assert e["wo_q"].codes.shape[2:] == store.wo_shape
+        assert store.code_elements() == sum(
+            int(np.prod(q.codes.shape)) for q in (e["wi_q"], e["wo_q"]))
+
 
 class TestPCW:
     def _hot_tracker(self, L=3, E=8):
