@@ -1,0 +1,8 @@
+"""Model step: device time of the jitted prefill program per 1024 prompt
+tokens, over the traced prefills (moves time to first token)."""
+
+from chipbench.tracereduce import prefill_ms_per_ktok
+
+
+def read(run):
+    return prefill_ms_per_ktok(run)
