@@ -36,7 +36,6 @@ State is split into two tiers so one engine can serve many requests
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import List, Optional
 
 import jax
@@ -56,6 +55,7 @@ from repro.hw.energy import CostLedger, ShardedCostLedger
 from repro.hw.specs import SYSTEM_PROFILES
 from repro.models.moe import RoutingPolicy
 from repro.models import model as MDL
+from repro.obs.hosttrace import HostTrace
 
 
 @dataclasses.dataclass
@@ -316,6 +316,9 @@ class PersistentEngine:
         # when attached via attach_tracer, every ledger charge emits one
         # attributed TraceEvent (see docs/observability.md).
         self.tracer = None
+        # Wall-clock spans of the host's work (repro.obs.hosttrace); the
+        # scheduler shares this instance.  Never read by the charge path.
+        self.host_trace = HostTrace()
 
         # moe pattern positions in order (matches aux stacking order)
         self.moe_positions = [i for i, s in enumerate(cfg.block_pattern)
@@ -368,12 +371,21 @@ class PersistentEngine:
         # masks that don't exist yet at prefill and keep natural top-k.
         prefill_policy = ecfg.policy if ecfg.policy.kind == "cumsum" \
             else None
-        self._jit_prefill = jax.jit(partial(
-            MDL.prefill, cfg=cfg, max_seq=ecfg.max_seq, collect_trace=True,
-            mat=ecfg.mat, quant_execution=qe, policy=prefill_policy))
-        self._jit_decode = jax.jit(partial(
-            MDL.decode_step, cfg=cfg, collect_trace=True,
-            policy=ecfg.policy, mat=ecfg.mat, quant_execution=qe))
+        # Named functions, not partials, so that the programs are called
+        # jit_prefill and jit_decode_step in HLO and profiler traces.
+        def prefill(params, **kw):
+            return MDL.prefill(params, cfg=cfg, max_seq=ecfg.max_seq,
+                               collect_trace=True, mat=ecfg.mat,
+                               quant_execution=qe, policy=prefill_policy,
+                               **kw)
+
+        def decode_step(params, **kw):
+            return MDL.decode_step(params, cfg=cfg, collect_trace=True,
+                                   policy=ecfg.policy, mat=ecfg.mat,
+                                   quant_execution=qe, **kw)
+
+        self._jit_prefill = jax.jit(prefill)
+        self._jit_decode = jax.jit(decode_step)
 
         # Non-expert resident weight bytes touched per decode step (INT8
         # per the paper's G128 non-expert quantization).
@@ -529,28 +541,36 @@ class PersistentEngine:
         streaming *and* the warmup reshape installs) under a
         tenant-partitioned cache; ignored otherwise.
         """
-        self._begin_request(label, inflight, tenant=tenant)
+        ht = self.host_trace
+        with ht.span("prefill", tokens=int(tokens.shape[1])):
+            self._begin_request(label, inflight, tenant=tenant)
 
-        logits, kv_cache, aux = self._jit_prefill(
-            self.qparams, tokens=tokens, **model_kwargs)
+            with ht.span("prefill.launch"):
+                logits, kv_cache, aux = self._jit_prefill(
+                    self.qparams, tokens=tokens, **model_kwargs)
 
-        ids = np.asarray(aux["moe"]["ids"])      # [n_periods, n_moe_pos, T, k]
-        gates = np.asarray(aux["moe"]["gates"]).astype(np.float64)
-        # `active` exists when prefill ran a routing policy (cumsum):
-        # deactivated slots must not charge fills or count as hotness.
-        # An all-True mask carries no information — normalize it to None
-        # so recorded traces don't serialize a redundant bool array per
-        # prompt (replay semantics are identical).
-        active = (np.asarray(aux["moe"]["active"], bool)
-                  if "active" in aux["moe"] else None)
-        if active is not None and active.all():
-            active = None
-        if self.recorder is not None:
-            self.recorder.on_prefill(ids, gates, active=active,
-                                     label=label, inflight=inflight,
-                                     tenant=tenant)
-        self._charge_prefill(ids, gates, active)
-        info = self._finish_prefill(label)
+            with ht.span("prefill.fetch"):
+                # [n_periods, n_moe_pos, T, k]
+                ids = np.asarray(aux["moe"]["ids"])
+                gates = np.asarray(aux["moe"]["gates"]).astype(np.float64)
+                # `active` exists when prefill ran a routing policy
+                # (cumsum): deactivated slots must not charge fills or
+                # count as hotness.  An all-True mask carries no
+                # information — normalize it to None so recorded traces
+                # don't serialize a redundant bool array per prompt
+                # (replay semantics are identical).
+                active = (np.asarray(aux["moe"]["active"], bool)
+                          if "active" in aux["moe"] else None)
+            if active is not None and active.all():
+                active = None
+            if self.recorder is not None:
+                self.recorder.on_prefill(ids, gates, active=active,
+                                         label=label, inflight=inflight,
+                                         tenant=tenant)
+            with ht.span("prefill.charge"):
+                self._charge_prefill(ids, gates, active)
+            with ht.span("prefill.warmup"):
+                info = self._finish_prefill(label)
         return logits, kv_cache, info
 
     # The three pieces below are the *model-free* half of prefill: they
@@ -722,13 +742,17 @@ class PersistentEngine:
 
         Returns ``(logits [B, V], kv_cache, StepCharge)``.
         """
-        ps = self._policy_state()
-        mask = None if slot_active is None \
-            else jnp.asarray(np.asarray(slot_active, bool))
-        logits, kv_cache, aux = self._jit_decode(
-            self.qparams, token=token, cache=kv_cache,
-            policy_state=ps, alpha=jnp.float32(alpha),
-            token_mask=mask, **model_kwargs)
+        ht = self.host_trace
+        with ht.span("decode"):
+            with ht.span("decode.policy"):
+                ps = self._policy_state()
+                mask = None if slot_active is None \
+                    else jnp.asarray(np.asarray(slot_active, bool))
+            with ht.span("decode.launch"):
+                logits, kv_cache, aux = self._jit_decode(
+                    self.qparams, token=token, cache=kv_cache,
+                    policy_state=ps, alpha=jnp.float32(alpha),
+                    token_mask=mask, **model_kwargs)
         charge = self.charge_decode_step(aux, slot_active=slot_active,
                                          slot_tenants=slot_tenants)
         return logits, kv_cache, charge
@@ -757,8 +781,11 @@ class PersistentEngine:
           ride the Flash channel behind demand fills, and only the layer
           that actually consumes a late slice stalls.
         """
-        return self.charge_step_trace(
-            _StepTrace.from_aux(aux, slot_active, slot_tenants))
+        ht = self.host_trace
+        with ht.span("charge"):
+            with ht.span("charge.fetch"):
+                tr = _StepTrace.from_aux(aux, slot_active, slot_tenants)
+            return self.charge_step_trace(tr)
 
     def charge_step_trace(self, tr: "_StepTrace") -> StepCharge:
         """Charge an already-assembled :class:`_StepTrace`.
@@ -786,7 +813,9 @@ class PersistentEngine:
         # charge-path state (the hotness tracker + the decode-step
         # counter), so a replay of the recorded trace recomputes the
         # identical migration sequence.
-        self._maybe_migrate()
+        ht = self.host_trace
+        with ht.span("charge.migrate"):
+            self._maybe_migrate()
         ctl = self.slo_controller
         T = tr.slot_mask.shape[0]
         if ctl is not None:
@@ -798,13 +827,15 @@ class PersistentEngine:
         tr.slot_critical_low = np.zeros(T, np.int64)
         replay = self._charge_async if self.ecfg.async_io \
             else self._charge_sync
-        charge = replay(tr)
+        with ht.span("charge.replay"):
+            charge = replay(tr)
         if ctl is not None:
-            actions = ctl.observe_step(charge.per_tenant or {},
-                                       charge.ledger_delta)
-            budgets = actions.get("budgets")
-            if budgets and hasattr(self.cache, "set_budgets"):
-                self.cache.set_budgets(budgets)
+            with ht.span("charge.control"):
+                actions = ctl.observe_step(charge.per_tenant or {},
+                                           charge.ledger_delta)
+                budgets = actions.get("budgets")
+                if budgets and hasattr(self.cache, "set_budgets"):
+                    self.cache.set_budgets(budgets)
         return charge
 
     # ---------------------------------------------------- observability
@@ -1772,15 +1803,11 @@ class SliceMoEEngine(PersistentEngine):
         step_metrics = []
 
         for step in range(n_steps):
-            ps = self._policy_state()
-            logits, self.kv_cache, aux = self._jit_decode(
-                self.qparams, token=token, cache=self.kv_cache,
-                policy_state=ps, alpha=jnp.float32(self.alpha),
-                **model_kwargs)
+            logits, self.kv_cache, charge = self.decode_batch(
+                token, self.kv_cache, alpha=self.alpha, **model_kwargs)
             token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             tokens_out.append(token)
 
-            charge = self.charge_decode_step(aux)
             step_miss = charge.miss_rate
             if self.controller is not None:
                 self.alpha = self.controller.update(step_miss)

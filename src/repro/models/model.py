@@ -183,14 +183,15 @@ def _attn_qkv(p: dict, x: jax.Array, cfg: ModelConfig, prefix: str = ""):
 def _self_attn_block(p: dict, x: jax.Array, cfg: ModelConfig, *,
                      causal: bool, positions: jax.Array,
                      window: Optional[int]):
-    h = L.rms_norm(x, p["norm"], cfg.norm_eps)
-    q, k, v = _attn_qkv(p, h, cfg)
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
-    o = L.attention(q, k, v, causal=causal, sliding_window=window,
-                    logit_softcap=cfg.logit_softcap)
-    o = o.reshape(x.shape[0], x.shape[1], -1) @ p["wo"]
-    return x + o, (k, v)
+    with jax.named_scope("attn"):
+        h = L.rms_norm(x, p["norm"], cfg.norm_eps)
+        q, k, v = _attn_qkv(p, h, cfg)
+        q = L.apply_rope(q, positions, cfg.rope_theta)
+        k = L.apply_rope(k, positions, cfg.rope_theta)
+        o = L.attention(q, k, v, causal=causal, sliding_window=window,
+                        logit_softcap=cfg.logit_softcap)
+        o = o.reshape(x.shape[0], x.shape[1], -1) @ p["wo"]
+        return x + o, (k, v)
 
 
 def _cross_attn_block(p: dict, x: jax.Array, enc_k: jax.Array,
@@ -213,18 +214,19 @@ def _ffn_block(p: dict, x: jax.Array, cfg: ModelConfig, spec: BlockSpec, *,
         h = L.rms_norm(x, p["mlp_norm"], cfg.norm_eps)
         x = x + L.mlp_apply(p["mlp"], h, cfg.mlp_type)
     elif spec.ffn == "moe":
-        h = L.rms_norm(x, p["moe_norm"], cfg.norm_eps)
-        b, s, d = h.shape
-        y, aux = M.moe_apply(
-            p["moe"], h.reshape(-1, d), cfg.moe,
-            use_lsb=use_lsb, gate_override=gate_override,
-            policy=policy, policy_state=policy_state, mat=mat,
-            token_mask=token_mask, quant_execution=quant_execution,
-            force_high_bit=force_high_bit)
-        x = x + y.reshape(b, s, d)
-        if not collect:
-            aux = {"aux_loss": aux["aux_loss"],
-                   "dropped_frac": aux["dropped_frac"]}
+        with jax.named_scope("moe"):
+            h = L.rms_norm(x, p["moe_norm"], cfg.norm_eps)
+            b, s, d = h.shape
+            y, aux = M.moe_apply(
+                p["moe"], h.reshape(-1, d), cfg.moe,
+                use_lsb=use_lsb, gate_override=gate_override,
+                policy=policy, policy_state=policy_state, mat=mat,
+                token_mask=token_mask, quant_execution=quant_execution,
+                force_high_bit=force_high_bit)
+            x = x + y.reshape(b, s, d)
+            if not collect:
+                aux = {"aux_loss": aux["aux_loss"],
+                       "dropped_frac": aux["dropped_frac"]}
     return x, aux
 
 
@@ -355,13 +357,14 @@ def forward(
 
 
 def unembed(params: dict, cfg: ModelConfig, h: jax.Array) -> jax.Array:
-    w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    logits = (h @ w.astype(h.dtype)).astype(jnp.float32)
-    if cfg.padded_vocab != cfg.vocab_size:
-        # mask pad columns so softmax / argmax / logsumexp ignore them
-        col = jnp.arange(cfg.padded_vocab)
-        logits = jnp.where(col < cfg.vocab_size, logits, -1e30)
-    return logits
+    with jax.named_scope("unembed"):
+        w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+        logits = (h @ w.astype(h.dtype)).astype(jnp.float32)
+        if cfg.padded_vocab != cfg.vocab_size:
+            # mask pad columns so softmax / argmax / logsumexp ignore them
+            col = jnp.arange(cfg.padded_vocab)
+            logits = jnp.where(col < cfg.vocab_size, logits, -1e30)
+        return logits
 
 
 def lm_loss(params: dict, cfg: ModelConfig, tokens: jax.Array,
@@ -593,89 +596,90 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
             key = f"pos{i}"
             p = period_params[key]
             if spec.mixer == "attn":
-                h = L.rms_norm(x, p["norm"], cfg.norm_eps)
-                q, k, v = _attn_qkv(p, h, cfg)
-                q = L.apply_rope(q, positions, cfg.rope_theta)
-                k = L.apply_rope(k, positions, cfg.rope_theta)
-                S_alloc = cache_in[key]["k"].shape[1]
-                ring = cfg.ring_kv
-                pos_w = (pos % S_alloc) if ring else pos
+                with jax.named_scope("attn"):
+                    h = L.rms_norm(x, p["norm"], cfg.norm_eps)
+                    q, k, v = _attn_qkv(p, h, cfg)
+                    q = L.apply_rope(q, positions, cfg.rope_theta)
+                    k = L.apply_rope(k, positions, cfg.rope_theta)
+                    S_alloc = cache_in[key]["k"].shape[1]
+                    ring = cfg.ring_kv
+                    pos_w = (pos % S_alloc) if ring else pos
 
-                def write_row(buf, val):
-                    # val: [B, 1, ...] — the new token's row per sequence.
+                    def write_row(buf, val):
+                        # val: [B, 1, ...] — the new token's row per sequence.
+                        if vector_pos:
+                            return buf.at[jnp.arange(b), pos_w].set(
+                                val[:, 0].astype(buf.dtype))
+                        start = (0, pos_w) + (0,) * (buf.ndim - 2)
+                        return jax.lax.dynamic_update_slice(
+                            buf, val.astype(buf.dtype), start)
+
+                    if cfg.kv_dtype == "int8":
+                        kq, ks = _quant_kv(k)
+                        vq, vs = _quant_kv(v)
+                        kc = write_row(cache_in[key]["k"], kq)
+                        vc = write_row(cache_in[key]["v"], vq)
+                        ksc = write_row(cache_in[key]["k_scale"], ks)
+                        vsc = write_row(cache_in[key]["v_scale"], vs)
+                        entry = {"k": kc, "v": vc, "k_scale": ksc,
+                                 "v_scale": vsc}
+                    else:
+                        kc = write_row(cache_in[key]["k"], k)
+                        vc = write_row(cache_in[key]["v"], v)
+                        ksc = vsc = None
+                        entry = {"k": kc, "v": vc}
+
+                    # Sliding-window decode reads only the last `window` cache
+                    # rows (true O(window) traffic, not a masked full read).
+                    S_cache = kc.shape[1]
                     if vector_pos:
-                        return buf.at[jnp.arange(b), pos_w].set(
-                            val[:, 0].astype(buf.dtype))
-                    start = (0, pos_w) + (0,) * (buf.ndim - 2)
-                    return jax.lax.dynamic_update_slice(
-                        buf, val.astype(buf.dtype), start)
-
-                if cfg.kv_dtype == "int8":
-                    kq, ks = _quant_kv(k)
-                    vq, vs = _quant_kv(v)
-                    kc = write_row(cache_in[key]["k"], kq)
-                    vc = write_row(cache_in[key]["v"], vq)
-                    ksc = write_row(cache_in[key]["k_scale"], ks)
-                    vsc = write_row(cache_in[key]["v_scale"], vs)
-                    entry = {"k": kc, "v": vc, "k_scale": ksc,
-                             "v_scale": vsc}
-                else:
-                    kc = write_row(cache_in[key]["k"], k)
-                    vc = write_row(cache_in[key]["v"], v)
-                    ksc = vsc = None
-                    entry = {"k": kc, "v": vc}
-
-                # Sliding-window decode reads only the last `window` cache
-                # rows (true O(window) traffic, not a masked full read).
-                S_cache = kc.shape[1]
-                if vector_pos:
-                    # Per-sequence lengths: rows diverge, so the compact
-                    # dynamic-slice read doesn't apply — read the full
-                    # cache and let the per-row mask in decode_attention
-                    # bound each sequence's valid prefix (and window).
-                    k_r, v_r = kc, vc
-                    ks_r, vs_r = ksc, vsc
-                    cur = jnp.minimum(pos + 1, S_cache) if ring else pos + 1
-                    win_mask = None if ring else window
-                elif ring:
-                    # ring buffer: every resident row is within the window;
-                    # attention is permutation-invariant so wraparound
-                    # order doesn't matter.
-                    k_r, v_r = kc, vc
-                    ks_r, vs_r = ksc, vsc
-                    cur = jnp.minimum(pos + 1, S_cache)
-                    win_mask = None
-                elif window is not None and S_cache > window:
-                    start = jnp.clip(pos + 1 - window, 0, S_cache - window)
-                    k_r = jax.lax.dynamic_slice_in_dim(kc, start, window, 1)
-                    v_r = jax.lax.dynamic_slice_in_dim(vc, start, window, 1)
-                    if ksc is not None:
-                        ks_r = jax.lax.dynamic_slice_in_dim(ksc, start,
-                                                            window, 1)
-                        vs_r = jax.lax.dynamic_slice_in_dim(vsc, start,
-                                                            window, 1)
-                    cur = pos + 1 - start
-                    win_mask = None
-                else:
-                    k_r, v_r = kc, vc
-                    ks_r, vs_r = ksc, vsc
-                    cur = pos + 1
-                    win_mask = window
-                if cfg.kv_dtype == "int8":
-                    k_f = _dequant_kv(k_r, ks_r, _dt(cfg))
-                    v_f = _dequant_kv(v_r, vs_r, _dt(cfg))
-                else:
-                    k_f, v_f = k_r, v_r
-                o = L.decode_attention(
-                    q[:, 0], k_f, v_f, cur, sliding_window=win_mask,
-                    logit_softcap=cfg.logit_softcap)
-                x = x + (o.reshape(b, -1) @ p["wo"])[:, None, :]
-                if cfg.is_encdec:
-                    x = _cross_attn_block(
-                        p, x, cache_in[key]["ck"], cache_in[key]["cv"], cfg)
-                    entry["ck"] = cache_in[key]["ck"]
-                    entry["cv"] = cache_in[key]["cv"]
-                cache_out[key] = entry
+                        # Per-sequence lengths: rows diverge, so the compact
+                        # dynamic-slice read doesn't apply — read the full
+                        # cache and let the per-row mask in decode_attention
+                        # bound each sequence's valid prefix (and window).
+                        k_r, v_r = kc, vc
+                        ks_r, vs_r = ksc, vsc
+                        cur = jnp.minimum(pos + 1, S_cache) if ring else pos + 1
+                        win_mask = None if ring else window
+                    elif ring:
+                        # ring buffer: every resident row is within the window;
+                        # attention is permutation-invariant so wraparound
+                        # order doesn't matter.
+                        k_r, v_r = kc, vc
+                        ks_r, vs_r = ksc, vsc
+                        cur = jnp.minimum(pos + 1, S_cache)
+                        win_mask = None
+                    elif window is not None and S_cache > window:
+                        start = jnp.clip(pos + 1 - window, 0, S_cache - window)
+                        k_r = jax.lax.dynamic_slice_in_dim(kc, start, window, 1)
+                        v_r = jax.lax.dynamic_slice_in_dim(vc, start, window, 1)
+                        if ksc is not None:
+                            ks_r = jax.lax.dynamic_slice_in_dim(ksc, start,
+                                                                window, 1)
+                            vs_r = jax.lax.dynamic_slice_in_dim(vsc, start,
+                                                                window, 1)
+                        cur = pos + 1 - start
+                        win_mask = None
+                    else:
+                        k_r, v_r = kc, vc
+                        ks_r, vs_r = ksc, vsc
+                        cur = pos + 1
+                        win_mask = window
+                    if cfg.kv_dtype == "int8":
+                        k_f = _dequant_kv(k_r, ks_r, _dt(cfg))
+                        v_f = _dequant_kv(v_r, vs_r, _dt(cfg))
+                    else:
+                        k_f, v_f = k_r, v_r
+                    o = L.decode_attention(
+                        q[:, 0], k_f, v_f, cur, sliding_window=win_mask,
+                        logit_softcap=cfg.logit_softcap)
+                    x = x + (o.reshape(b, -1) @ p["wo"])[:, None, :]
+                    if cfg.is_encdec:
+                        x = _cross_attn_block(
+                            p, x, cache_in[key]["ck"], cache_in[key]["cv"], cfg)
+                        entry["ck"] = cache_in[key]["ck"]
+                        entry["cv"] = cache_in[key]["cv"]
+                    cache_out[key] = entry
             else:
                 h = L.rms_norm(x, p["ssm_norm"], cfg.norm_eps)
                 y, st, cb = S.ssm_decode_step(

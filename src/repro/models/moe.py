@@ -350,27 +350,28 @@ def moe_apply(
                                 experts["wo_zps"], mat.high_bits,
                                 mat.group_size, True)
 
-    if wi_qt is not None and quant_exec:
-        # Quantized execution: the packed codes ARE the compute format.
-        # No dense expert tensor is materialized (and hence no
-        # dequant-tile shard_hint workaround is needed — the kernel
-        # reads the codes at their native sharding).
-        ye = _expert_ffn_quant(xe, wi_qt, wo_qt, use_lsb, mat.shift,
-                               cfg.mlp_type)
-    elif wi_qt is not None:
-        # Dense-dequant reference path: materialize per-expert f32/bf16
-        # weights each step (gather-then-dequantize).
-        wi = _dequant_experts(wi_qt, use_lsb, mat.shift, x.dtype)
-        wo = _dequant_experts(wo_qt, use_lsb, mat.shift, x.dtype)
-        if "wi_codes" in experts:
-            # Pin the dequantized tiles to the codes' sharding: without
-            # this GSPMD replicates them (a 66 GB/step all-gather on
-            # maverick — EXPERIMENTS.md §Perf hillclimb 1).
-            wi = shard_hint(wi, "model", None, "data")
-            wo = shard_hint(wo, "model", "data", None)
-        ye = _expert_ffn(xe, wi, wo, cfg.mlp_type)
-    else:
-        ye = _expert_ffn(xe, experts["wi"], experts["wo"], cfg.mlp_type)
+    with jax.named_scope("experts"):
+        if wi_qt is not None and quant_exec:
+            # Quantized execution: the packed codes ARE the compute format.
+            # No dense expert tensor is materialized (and hence no
+            # dequant-tile shard_hint workaround is needed — the kernel
+            # reads the codes at their native sharding).
+            ye = _expert_ffn_quant(xe, wi_qt, wo_qt, use_lsb, mat.shift,
+                                   cfg.mlp_type)
+        elif wi_qt is not None:
+            # Dense-dequant reference path: materialize per-expert f32/bf16
+            # weights each step (gather-then-dequantize).
+            wi = _dequant_experts(wi_qt, use_lsb, mat.shift, x.dtype)
+            wo = _dequant_experts(wo_qt, use_lsb, mat.shift, x.dtype)
+            if "wi_codes" in experts:
+                # Pin the dequantized tiles to the codes' sharding: without
+                # this GSPMD replicates them (a 66 GB/step all-gather on
+                # maverick — EXPERIMENTS.md §Perf hillclimb 1).
+                wi = shard_hint(wi, "model", None, "data")
+                wo = shard_hint(wo, "model", "data", None)
+            ye = _expert_ffn(xe, wi, wo, cfg.mlp_type)
+        else:
+            ye = _expert_ffn(xe, experts["wi"], experts["wo"], cfg.mlp_type)
     ye = shard_hint(ye, "model", None, None)
     y = combine(ye, ids, positions, keep, gates)
     y = shard_hint(y, ("pod", "data"), None)

@@ -8,6 +8,9 @@
   step via ``scheduler.attach_metrics(MetricsRegistry())``;
 * :mod:`repro.obs.report` — stall/overlap/waste analysis of an
   exported trace (CLI: ``scripts/trace_report.py``).
+* :mod:`repro.obs.hosttrace` — wall-clock host spans and counters
+  (``engine.host_trace``; profiler annotations, a bounded ring,
+  collector pauses, compiles by span).
 
 See docs/observability.md for the trace schema, span model and
 metrics catalog.

@@ -19,8 +19,9 @@ Two export formats, both schema-stable:
 each :class:`~repro.serving.telemetry.StepRecord` into the registry and
 samples engine-side state (cache occupancy, ledger traffic, prefetch
 outcomes, controller actuation, shard balance) per decode step —
-replacing ad-hoc per-consumer snapshot plumbing with one catalog (see
-docs/observability.md).
+and the engine's wall-clock host-span totals
+(:mod:`repro.obs.hosttrace`) — replacing ad-hoc per-consumer snapshot
+plumbing with one catalog (see docs/observability.md).
 """
 
 from __future__ import annotations
@@ -356,6 +357,24 @@ class MetricsSampler:
             for key in ("issued", "useful", "late", "wasted"):
                 r.counter(f"prefetch_{key}_total",
                           "prefetch outcome").set_to(s[key])
+        ht = getattr(eng, "host_trace", None)
+        if ht is not None:
+            # Wall-clock totals (repro.obs.hosttrace).  Copied with list():
+            # a collector pause can add a key while this loop runs.
+            for name, secs in list(ht.seconds.items()):
+                r.counter("host_span_seconds_total",
+                          "wall seconds in host spans",
+                          span=name).set_to(secs)
+                r.counter("host_span_count_total", "host spans closed",
+                          span=name).set_to(ht.counts[name])
+            for gen, secs in list(ht.gc_seconds.items()):
+                r.counter("host_gc_seconds_total",
+                          "wall seconds of collector pauses",
+                          generation=str(gen)).set_to(secs)
+            for name, n in list(ht.compiles.items()):
+                r.counter("jit_compiles_total",
+                          "XLA compiles by innermost host span",
+                          span=name).set_to(n)
         ctl = getattr(eng, "slo_controller", None)
         if ctl is not None:
             r.counter("controller_actions_total",

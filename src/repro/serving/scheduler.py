@@ -33,7 +33,6 @@ simplification documented in docs/serving.md).
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Callable, Deque, List, Optional
 
@@ -41,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import PersistentEngine
+from repro.obs.hosttrace import now_ns
 from repro.serving.telemetry import (FleetTelemetry, RequestRecord,
                                      StepRecord)
 
@@ -59,8 +59,8 @@ class Request:
 class Completion:
     request_id: int
     tokens: np.ndarray
-    prefill_s: float              # wall seconds (host)
-    decode_s: float
+    prefill_s: float              # wall seconds (host): admission -> token
+    decode_s: float               # first token -> retirement
     metrics: Optional[dict] = None
 
 
@@ -102,8 +102,6 @@ class ActiveSeq:
     last_token: int = 0
     generated: List[int] = dataclasses.field(default_factory=list)
     ledger_base: Optional[dict] = None # snapshot at decode start
-    wall_prefill_s: float = 0.0
-    wall_decode_t0: float = 0.0
     prefill_end_t: float = 0.0         # sim clock when prefill settled
 
 
@@ -113,6 +111,8 @@ class ContinuousBatchingScheduler:
     def __init__(self, engine: PersistentEngine,
                  cfg: Optional[SchedulerConfig] = None):
         self.engine = engine
+        # Wall-clock spans go to the engine's host trace (one per engine).
+        self.host_trace = engine.host_trace
         self.cfg = cfg or SchedulerConfig()
         if self.cfg.max_batch < 1:
             raise ValueError("max_batch must be >= 1")
@@ -184,7 +184,8 @@ class ContinuousBatchingScheduler:
             request_id=req.request_id,
             tenant=getattr(req, "tenant", "default"),
             prompt_len=len(req.prompt),
-            arrival_t=getattr(req, "arrival_time", 0.0))
+            arrival_t=getattr(req, "arrival_time", 0.0),
+            submit_wall_ns=now_ns())
         if len(self.queue) >= self.cfg.max_queue or not self.servable(req):
             self.telemetry.on_reject(record)
             return False
@@ -240,7 +241,7 @@ class ContinuousBatchingScheduler:
     def _admit_one(self, req: Request, slot: int) -> None:
         record = self.telemetry.requests[req.request_id]
         record.admit_t = self.sim_time
-        t0 = time.perf_counter()
+        record.admit_wall_ns = now_ns()
         prompt = self._clip_prompt(req)
         # Per-request stats epochs are only meaningful when requests run
         # one at a time; under batching, concurrent sequences would bleed
@@ -253,7 +254,6 @@ class ContinuousBatchingScheduler:
         if self.engine.recorder is not None:
             self.engine.recorder.annotate_prefill(
                 request_id=req.request_id, tenant=req.tenant)
-        wall = time.perf_counter() - t0
         self._advance_clock()
         trc = getattr(self.engine, "tracer", None)
         if trc is not None:
@@ -272,11 +272,11 @@ class ContinuousBatchingScheduler:
             controller=self.engine.new_controller(),
             last_token=int(jnp.argmax(logits, -1)[0]),
             ledger_base=self.engine.ledger.snapshot(),
-            wall_prefill_s=wall,
-            wall_decode_t0=time.perf_counter(),
             prefill_end_t=self.sim_time)
-        self.batch_cache = self.engine.install_slot(
-            self.batch_cache, kv_cache, slot)
+        record.first_token_wall_ns = now_ns()
+        with self.host_trace.span("sched.install"):
+            self.batch_cache = self.engine.install_slot(
+                self.batch_cache, kv_cache, slot)
         self.slots[slot] = seq
 
     def _admit(self) -> int:
@@ -292,7 +292,9 @@ class ContinuousBatchingScheduler:
                 else:
                     break
             self.queue.popleft()
-            self._admit_one(req, free.pop(0))
+            with self.host_trace.span("sched.admit", request=req.request_id,
+                                      prompt_len=len(req.prompt)):
+                self._admit_one(req, free.pop(0))
             admitted += 1
         return admitted
 
@@ -316,8 +318,9 @@ class ContinuousBatchingScheduler:
             jnp.asarray(tokens), self.batch_cache,
             alpha=alpha, slot_active=slot_mask,
             slot_tenants=slot_tenants)
-        next_tokens = np.asarray(
-            jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        with self.host_trace.span("sched.sample"):
+            next_tokens = np.asarray(
+                jnp.argmax(logits, axis=-1).astype(jnp.int32))
         step_latency = self._advance_clock()
         trc = getattr(self.engine, "tracer", None)
         if trc is not None:
@@ -354,10 +357,13 @@ class ContinuousBatchingScheduler:
                 (seq.request.eos_token is not None
                  and tok == seq.request.eos_token)
             if done:
-                self._retire(seq)
+                with self.host_trace.span("sched.retire"):
+                    self._retire(seq)
 
     def _retire(self, seq: ActiveSeq) -> None:
-        seq.record.finish_t = self.sim_time
+        rec = seq.record
+        rec.finish_t = self.sim_time
+        rec.finish_wall_ns = now_ns()
         trc = getattr(self.engine, "tracer", None)
         if trc is not None:
             rid = seq.request.request_id
@@ -374,8 +380,8 @@ class ContinuousBatchingScheduler:
         self.completions.append(Completion(
             request_id=seq.request.request_id,
             tokens=toks,
-            prefill_s=seq.wall_prefill_s,
-            decode_s=time.perf_counter() - seq.wall_decode_t0,
+            prefill_s=1e-9 * (rec.first_token_wall_ns - rec.admit_wall_ns),
+            decode_s=1e-9 * (rec.finish_wall_ns - rec.first_token_wall_ns),
             metrics={
                 "ttft_s": seq.record.ttft,
                 "queue_delay_s": seq.record.queue_delay,
@@ -397,11 +403,14 @@ class ContinuousBatchingScheduler:
     # ------------------------------------------------------------------ run
     def step(self) -> bool:
         """One scheduler tick.  Returns False when fully idle."""
-        self._admit()
-        if self.n_active() == 0:
-            return bool(self.queue)
-        self._decode_step()
-        return True
+        ht = self.host_trace
+        with ht.span("sched.step"):
+            self._admit()
+            if self.n_active() == 0:
+                return bool(self.queue)
+            with ht.span("sched.decode"):
+                self._decode_step()
+            return True
 
     def run(self) -> List[Completion]:
         """Drive until the queue drains and every sequence retires."""

@@ -50,7 +50,8 @@ def percentile(values: List[float], p: float) -> float:
 
 @dataclasses.dataclass
 class RequestRecord:
-    """Lifecycle timestamps (simulated clock) and counters for one request."""
+    """Lifecycle timestamps (simulated clock, and host wall clock) and
+    counters for one request."""
 
     request_id: int
     tenant: str = "default"
@@ -64,6 +65,13 @@ class RequestRecord:
     truncated: bool = False         # prompt clipped to fit max_seq budget
     miss_sum: float = 0.0           # per-step selection-weighted miss rates
     miss_steps: int = 0
+    # Host wall clock (repro.obs.hosttrace.now_ns), beside the simulated
+    # times above: submitted, prefill started, the prefill's token on
+    # the host, retired.  0 until stamped.
+    submit_wall_ns: int = 0
+    admit_wall_ns: int = 0
+    first_token_wall_ns: int = 0
+    finish_wall_ns: int = 0
 
     @property
     def ttft(self) -> float:

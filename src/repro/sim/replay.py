@@ -43,6 +43,7 @@ from repro.core.slices import ExpertSliceStore
 from repro.core.warmup import HotnessTracker
 from repro.hw.specs import SYSTEM_PROFILES
 from repro.models.moe import RoutingPolicy
+from repro.obs.hosttrace import HostTrace
 from repro.sim.trace import Trace, TraceMeta
 
 __all__ = ["engine_config_from_meta", "ReplayEngine", "ReplayReport",
@@ -231,6 +232,7 @@ class ReplayEngine(PersistentEngine):
         # same ledgers the live engine uses — replay emits the identical
         # event stream (the live≡replay trace-equivalence gate).
         self.tracer = None
+        self.host_trace = HostTrace()
         self.buddies = None
         self.prefetcher = ecfg.build_prefetcher(
             self.n_moe_layers, self.n_experts)
