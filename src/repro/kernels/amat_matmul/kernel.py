@@ -15,6 +15,14 @@ the MSB+LSB and the MSB-only dequant constants branch-free.  This is the
 quantized-execution path of the expert FFN; one weight matrix at a
 static precision is the case E=1 (``ops.amat_matmul``).
 
+The codes may carry a leading period axis (``[P, E, K, N]``, the model's
+params stacked over its layer scan).  The period to read is a second
+scalar-prefetch operand that the BlockSpec index maps return as the
+leading block index, so the kernel DMAs its tiles straight out of the
+stacked params.  Handing it ``codes[p]`` instead makes XLA copy the
+whole period out first: a custom call cannot read a sliced operand in
+place.  Unstacked ``[E, K, N]`` codes are the case P=1, period 0.
+
 Tiling (what the TPU compiler accepts, see docs/kernels.md):
 
 * Each block spans the whole contraction axis K, so the group metadata
@@ -63,38 +71,43 @@ def _dequant_tile(codes, s, z, hi, *, group_size: int, shift: int, dtype):
     return w.reshape(K, bn).astype(dtype)
 
 
-def _amat_batched_kernel(u_ref, x_ref, c_ref, s_ref, z_ref, o_ref, *,
-                         group_size: int, shift: int):
+def _amat_batched_kernel(l_ref, u_ref, x_ref, c_ref, s_ref, z_ref, o_ref,
+                         *, group_size: int, shift: int):
     hi = u_ref[pl.program_id(0)] > 0                # scalar-prefetched flag
     x = x_ref[0]                                    # [bm, K]
-    w = _dequant_tile(c_ref[0], s_ref[0], z_ref[0], hi,
+    w = _dequant_tile(c_ref[0, 0], s_ref[0, 0], z_ref[0, 0], hi,
                       group_size=group_size, shift=shift, dtype=x.dtype)
     o_ref[0] = jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def amat_batched_matmul_pallas(x, codes, scales, zps, use_lsb, *,
+def amat_batched_matmul_pallas(x, codes, scales, zps, use_lsb, layer=0, *,
                                group_size: int = 32, shift: int = 4,
                                bm: int = 128, bn: int = 256,
                                interpret: bool = False):
     """Per-expert fused dequant-matmul on packed AMAT codes.
 
-    x: [E, M, K]; codes: [E, K, N] uint8; scales/zps: [E, K//G, N];
-    use_lsb: [E] (bool/int) — expert ``e`` computes at high precision iff
-    ``use_lsb[e]``.  Returns [E, M, N] f32.  ``N`` must be a multiple of
-    ``bn`` (or ``bn >= N``); ``M`` is padded to a multiple of ``bm``.
+    x: [E, M, K]; codes: [E, K, N] uint8, or [P, E, K, N] stacked over
+    periods and read at period ``layer`` (a scalar, traced or not);
+    scales/zps: [(P,) E, K//G, N]; use_lsb: [E] (bool/int) — expert
+    ``e`` computes at high precision iff ``use_lsb[e]``.  Returns
+    [E, M, N] f32.  ``N`` must be a multiple of ``bn`` (or
+    ``bn >= N``); ``M`` is padded to a multiple of ``bm``.
 
-    ``use_lsb`` travels via scalar prefetch: it is resident in SMEM
-    before the grid starts, so per-expert precision selection costs no
-    extra DMA and no grid restructuring — DBSC's per-step high/low-bit
-    decisions become per-expert dequant shifts inside one kernel launch.
+    ``layer`` and ``use_lsb`` travel via scalar prefetch: they are
+    resident in SMEM before the grid starts, so picking the period and
+    per-expert precision costs no extra DMA and no grid restructuring —
+    DBSC's per-step high/low-bit decisions become per-expert dequant
+    shifts inside one kernel launch.
     """
+    if codes.ndim == 3:                             # the case P=1
+        codes, scales, zps = codes[None], scales[None], zps[None]
     E, M, K = x.shape
-    N = codes.shape[2]
-    assert codes.shape == (E, K, N), (codes.shape, x.shape)
+    P, N = codes.shape[0], codes.shape[3]
+    assert codes.shape == (P, E, K, N), (codes.shape, x.shape)
     assert K % group_size == 0
-    assert scales.shape == zps.shape == (E, K // group_size, N)
+    assert scales.shape == zps.shape == (P, E, K // group_size, N)
     bm, bn = min(bm, M), min(bn, N)
     assert N % bn == 0, f"pad N to a multiple of bn: {N} vs {bn}"
     m_pad = (-M) % bm
@@ -103,21 +116,25 @@ def amat_batched_matmul_pallas(x, codes, scales, zps, use_lsb, *,
         x = jnp.pad(x, ((0, 0), (0, m_pad), (0, 0)))
     Mp = M + m_pad
     g = K // group_size
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
     u = use_lsb.astype(jnp.int32)
 
     kernel = functools.partial(_amat_batched_kernel, group_size=group_size,
                                shift=shift)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(E, N // bn, Mp // bm),
         in_specs=[
-            pl.BlockSpec((1, bm, K), lambda e, j, i, u_ref: (e, i, 0)),
-            pl.BlockSpec((1, K, bn), lambda e, j, i, u_ref: (e, 0, j)),
-            pl.BlockSpec((1, g, bn), lambda e, j, i, u_ref: (e, 0, j)),
-            pl.BlockSpec((1, g, bn), lambda e, j, i, u_ref: (e, 0, j)),
+            pl.BlockSpec((1, bm, K), lambda e, j, i, l_ref, u_ref: (e, i, 0)),
+            pl.BlockSpec((1, 1, K, bn),
+                         lambda e, j, i, l_ref, u_ref: (l_ref[0], e, 0, j)),
+            pl.BlockSpec((1, 1, g, bn),
+                         lambda e, j, i, l_ref, u_ref: (l_ref[0], e, 0, j)),
+            pl.BlockSpec((1, 1, g, bn),
+                         lambda e, j, i, l_ref, u_ref: (l_ref[0], e, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn),
-                               lambda e, j, i, u_ref: (e, i, j)),
+                               lambda e, j, i, l_ref, u_ref: (e, i, j)),
     )
     out = pl.pallas_call(
         kernel,
@@ -125,6 +142,5 @@ def amat_batched_matmul_pallas(x, codes, scales, zps, use_lsb, *,
         out_shape=jax.ShapeDtypeStruct((E, Mp, N), jnp.float32),
         name="amat_expert_matmul",
         interpret=interpret,
-    )(u, x, codes, scales, zps)
+    )(lyr, u, x, codes, scales, zps)
     return out[:, :M] if m_pad else out
-

@@ -30,12 +30,14 @@ def _pad_to(x, m, axis):
 
 @partial(jax.jit, static_argnames=("group_size", "shift", "bm", "bn",
                                    "interpret"))
-def amat_expert_matmul(x, codes, scales, zps, use_lsb, *,
+def amat_expert_matmul(x, codes, scales, zps, use_lsb, layer=0, *,
                        group_size: int = 32, shift: int = 4,
                        bm: int = 128, bn: int = 256,
                        interpret: bool | None = None):
     """[E, M, K] @ per-expert-dequant([E, K, N] codes) -> [E, M, N] f32.
 
+    ``codes``/``scales``/``zps`` may be stacked over periods
+    (``[P, E, K, N]``); the kernel then reads period ``layer`` in place.
     ``use_lsb`` [E] selects MSB+LSB (high-bit) vs MSB-only dequant per
     expert inside the kernel.  M is padded in-kernel; N is padded here
     to a multiple of ``bn`` (zero scales null the pad region).  Expert
@@ -43,21 +45,21 @@ def amat_expert_matmul(x, codes, scales, zps, use_lsb, *,
     """
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    N = codes.shape[2]
+    N = codes.shape[-1]
     bn_ = min(bn, N)
     out = amat_batched_matmul_pallas(
-        x, _pad_to(codes, bn_, 2), _pad_to(scales, bn_, 2),
-        _pad_to(zps, bn_, 2), use_lsb, group_size=group_size, shift=shift,
-        bm=bm, bn=bn_, interpret=interpret)
+        x, _pad_to(codes, bn_, -1), _pad_to(scales, bn_, -1),
+        _pad_to(zps, bn_, -1), use_lsb, layer, group_size=group_size,
+        shift=shift, bm=bm, bn=bn_, interpret=interpret)
     return out[:, :, :N]
 
 
-def amat_expert_matmul_qt(x, qt: QuantizedTensor, use_lsb, *, shift: int,
-                          **kw):
+def amat_expert_matmul_qt(x, qt: QuantizedTensor, use_lsb, layer=0, *,
+                          shift: int, **kw):
     """QuantizedTensor convention for the batched expert kernel."""
     assert qt.asymmetric, "AMAT kernel expects asymmetric group quant"
     return amat_expert_matmul(x, qt.codes, qt.scales, qt.zero_points,
-                              use_lsb, group_size=qt.group_size,
+                              use_lsb, layer, group_size=qt.group_size,
                               shift=shift, **kw)
 
 

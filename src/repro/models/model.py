@@ -208,7 +208,7 @@ def _cross_attn_block(p: dict, x: jax.Array, enc_k: jax.Array,
 def _ffn_block(p: dict, x: jax.Array, cfg: ModelConfig, spec: BlockSpec, *,
                collect, use_lsb=None, gate_override=None,
                policy=None, policy_state=None, mat=None, token_mask=None,
-               quant_execution=None, force_high_bit=False):
+               quant_execution=None, force_high_bit=False, layer=None):
     aux = None
     if spec.ffn == "dense":
         h = L.rms_norm(x, p["mlp_norm"], cfg.norm_eps)
@@ -222,7 +222,7 @@ def _ffn_block(p: dict, x: jax.Array, cfg: ModelConfig, spec: BlockSpec, *,
                 use_lsb=use_lsb, gate_override=gate_override,
                 policy=policy, policy_state=policy_state, mat=mat,
                 token_mask=token_mask, quant_execution=quant_execution,
-                force_high_bit=force_high_bit)
+                force_high_bit=force_high_bit, layer=layer)
             x = x + y.reshape(b, s, d)
             if not collect:
                 aux = {"aux_loss": aux["aux_loss"],
@@ -234,6 +234,38 @@ def _ssm_block(p: dict, x: jax.Array, cfg: ModelConfig):
     h = L.rms_norm(x, p["ssm_norm"], cfg.norm_eps)
     y = S.ssm_forward(p["ssm"], h, cfg.ssm)
     return x + y
+
+
+def _scan_periods(body, carry, blocks: dict, cfg: ModelConfig, xs=None):
+    """``lax.scan`` of ``body(carry, period_params, layer, xs_p)`` over
+    the model's periods; ``layer`` is the period index.
+
+    Quantized expert tensors (``experts/{wi_q,wo_q}`` or the flat
+    ``wi_codes/...`` form) stay out of the scanned inputs: each period
+    sees them whole (``[P, E, K, N]``) and its MoE reads period ``layer``
+    (see :func:`repro.models.moe.moe_apply`).  Scanned, they would reach
+    the body as per-period slices, and the expert kernel, a custom call,
+    cannot read a slice in place: XLA would copy every layer's codes out
+    of the params on every step.
+    """
+    held = {}
+    scanned = {}
+    for key, blk in blocks.items():
+        experts = blk.get("moe", {}).get("experts", {})
+        if "wi_q" in experts or "wi_codes" in experts:
+            held[key] = experts
+            blk = {**blk, "moe": {**blk["moe"], "experts": {}}}
+        scanned[key] = blk
+
+    def step(c, inputs):
+        layer, period_params, xs_p = inputs
+        period_params = {
+            k: {**p, "moe": {**p["moe"], "experts": held[k]}}
+            if k in held else p for k, p in period_params.items()}
+        return body(c, period_params, layer, xs_p)
+
+    return jax.lax.scan(step, carry,
+                        (jnp.arange(cfg.n_periods), scanned, xs))
 
 
 # ==========================================================================
@@ -309,7 +341,7 @@ def forward(
 
     pattern = cfg.block_pattern
 
-    def period_body(x, period_params):
+    def period_body(x, period_params, layer, _):
         if cfg.seq_parallel:
             # Megatron-style sequence parallelism: the residual stream is
             # seq-sharded over the model axis between blocks, turning the
@@ -330,7 +362,8 @@ def forward(
             else:
                 x = _ssm_block(p, x, cfg)
             x, aux = _ffn_block(p, x, cfg, spec, collect=collect_trace,
-                                mat=mat, quant_execution=quant_execution)
+                                mat=mat, quant_execution=quant_execution,
+                                layer=layer)
             if aux is not None:
                 auxes.append(aux)
         if auxes:
@@ -345,7 +378,7 @@ def forward(
         body = jax.checkpoint(period_body, prevent_cse=False, policy=policy)
     else:
         body = jax.checkpoint(period_body, prevent_cse=False)
-    x, aux_stacked = jax.lax.scan(body, x, params["blocks"])
+    x, aux_stacked = _scan_periods(body, x, params["blocks"], cfg)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     aux = {}
     if aux_stacked:
@@ -487,7 +520,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
 
     pattern = cfg.block_pattern
 
-    def period_body(x, period_params):
+    def period_body(x, period_params, layer, _):
         cache_entries = {}
         auxes = []
         for i, spec in enumerate(pattern):
@@ -522,7 +555,8 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
             x, aux = _ffn_block(p, x, cfg, spec, collect=collect_trace,
                                 mat=mat, quant_execution=quant_execution,
                                 policy=policy,
-                                force_high_bit=policy is not None)
+                                force_high_bit=policy is not None,
+                                layer=layer)
             if aux is not None:
                 auxes.append(aux)
         stacked = {}
@@ -531,8 +565,8 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
                        for k in auxes[0]}
         return x, (cache_entries, stacked)
 
-    x, (cache_stacked, aux_stacked) = jax.lax.scan(
-        period_body, x, params["blocks"])
+    x, (cache_stacked, aux_stacked) = _scan_periods(
+        period_body, x, params["blocks"], cfg)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, cfg, x[:, -1])
 
@@ -587,9 +621,8 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
     window = cfg.sliding_window if (use_window or cfg.always_swa) else None
     pattern = cfg.block_pattern
 
-    def period_body(carry, xs):
-        x = carry
-        period_params, cache_in, overrides = xs
+    def period_body(x, period_params, layer, xs):
+        cache_in, overrides = xs
         cache_out = {}
         auxes = []
         for i, spec in enumerate(pattern):
@@ -700,7 +733,8 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
                                 use_lsb=ul, gate_override=go,
                                 policy=policy, policy_state=ps, mat=mat,
                                 token_mask=token_mask,
-                                quant_execution=quant_execution)
+                                quant_execution=quant_execution,
+                                layer=layer)
             if aux is not None:
                 auxes.append(aux)
         stacked = {}
@@ -718,16 +752,8 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
         overrides["policy_state"] = policy_state
 
     layer_cache = {k: v for k, v in cache.items() if k != "pos"}
-    xs = (params["blocks"], layer_cache, overrides if overrides else None)
-    if overrides:
-        x, (new_cache, aux_stacked) = jax.lax.scan(period_body, x, xs)
-    else:
-        # keep xs structure static when no overrides are present
-        def body_no_ov(c, xs2):
-            pp, ci = xs2
-            return period_body(c, (pp, ci, None))
-        x, (new_cache, aux_stacked) = jax.lax.scan(
-            body_no_ov, x, (params["blocks"], layer_cache))
+    x, (new_cache, aux_stacked) = _scan_periods(
+        period_body, x, params["blocks"], cfg, (layer_cache, overrides))
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, cfg, x[:, 0])

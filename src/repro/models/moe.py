@@ -185,22 +185,24 @@ def _expert_ffn(xe: jax.Array, wi: jax.Array, wo: jax.Array,
 def _expert_ffn_quant(xe: jax.Array, wi_q: QuantizedTensor,
                       wo_q: QuantizedTensor,
                       use_lsb: Optional[jax.Array], shift: int,
-                      mlp_type: str) -> jax.Array:
+                      mlp_type: str, layer) -> jax.Array:
     """Expert FFN computed *directly on packed AMAT codes* (no dense
     weight tensor is ever materialized — the paper's in-front-of-compute
     dequantization, here fused into the Pallas matmul).
 
     ``use_lsb`` [E] selects the per-expert dequant shift inside the
     kernel.  Both projections read their codes in the canonical K-major
-    layout.
+    layout; stacked ``[P, E, K, N]`` tensors are read at period
+    ``layer`` inside the kernel.
     """
     from repro.kernels.amat_matmul.ops import amat_expert_matmul_qt
 
     ul = use_lsb if use_lsb is not None \
         else jnp.ones((xe.shape[0],), bool)
-    h = amat_expert_matmul_qt(xe, wi_q, ul, shift=shift).astype(xe.dtype)
+    h = amat_expert_matmul_qt(xe, wi_q, ul, layer,
+                              shift=shift).astype(xe.dtype)
     h = _ffn_activation(h, mlp_type, xe.dtype)
-    y = amat_expert_matmul_qt(h, wo_q, ul, shift=shift)
+    y = amat_expert_matmul_qt(h, wo_q, ul, layer, shift=shift)
     return y.astype(xe.dtype)
 
 
@@ -233,6 +235,7 @@ def moe_apply(
     rng: Optional[jax.Array] = None,
     quant_execution: Optional[bool] = None,  # None -> policy decides
     force_high_bit: bool = False,  # prefill: policy routes, compute hi-bit
+    layer: Optional[jax.Array] = None,  # period of stacked quantized experts
 ):
     """Full MoE layer.  Returns (y [T, d], aux: dict).
 
@@ -241,6 +244,12 @@ def moe_apply(
       experts:  {'wi': [E, d, F(|2F)] float}  OR
                 {'wi_q': QuantizedTensor, 'wo_q': QuantizedTensor}
       shared:   optional dense-MLP params applied to every token
+
+    ``layer``: the quantized expert tensors hold every period of the
+    model's layer scan (``[P, E, K, N]``) and this layer is period
+    ``layer``.  The kernel picks the period in its index maps, so no
+    per-period copy of the codes is made; the dense-dequant path indexes
+    the same leaves.  Float expert weights are always the layer's own.
 
     ``token_mask`` excludes padding rows (retired/empty batch slots in
     continuous-batching decode) from routing entirely: their ids are
@@ -357,10 +366,14 @@ def moe_apply(
             # dequant-tile shard_hint workaround is needed — the kernel
             # reads the codes at their native sharding).
             ye = _expert_ffn_quant(xe, wi_qt, wo_qt, use_lsb, mat.shift,
-                                   cfg.mlp_type)
+                                   cfg.mlp_type,
+                                   0 if layer is None else layer)
         elif wi_qt is not None:
             # Dense-dequant reference path: materialize per-expert f32/bf16
             # weights each step (gather-then-dequantize).
+            if layer is not None:
+                wi_qt, wo_qt = jax.tree.map(lambda a: a[layer],
+                                            (wi_qt, wo_qt))
             wi = _dequant_experts(wi_qt, use_lsb, mat.shift, x.dtype)
             wo = _dequant_experts(wo_qt, use_lsb, mat.shift, x.dtype)
             if "wi_codes" in experts:

@@ -9,7 +9,7 @@ import pytest
 pytestmark = pytest.mark.slow
 from hypothesis import given, settings, strategies as st
 
-from repro.core.amat import PAPER_CONFIGS, amat_quantize
+from repro.core.amat import MAT84, PAPER_CONFIGS, amat_quantize
 from repro.kernels.amat_matmul.kernel import amat_batched_matmul_pallas
 from repro.kernels.amat_matmul.ops import (amat_expert_matmul_qt,
                                            amat_matmul, amat_matmul_qt)
@@ -172,6 +172,55 @@ class TestAmatBatchedMatmul:
         for o in outs[1:]:
             np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                        atol=1e-4)
+
+
+class TestStackedPeriods:
+    """Codes stacked over periods ([P, E, K, N]) and read at a period
+    picked by the index maps must give, bit for bit, what the unstacked
+    call on that period's codes gives."""
+
+    @pytest.mark.parametrize("kn", [(64, 256), (1408, 128)],
+                             ids=["wi", "wo_k1408"])
+    @pytest.mark.parametrize("m", [5, 130])
+    def test_each_period_matches_its_own_codes(self, rng, kn, m):
+        P, E = 3, 3
+        K, N = kn
+        x = jax.random.normal(rng, (E, m, K))
+        w = jax.random.normal(jax.random.fold_in(rng, 1),
+                              (P, E, K, N)) * 0.1
+        qt = amat_quantize(w, MAT84)
+        ul = jnp.array([True, False, True])
+        outs = []
+        for layer in range(P):
+            own = amat_quantize(w[layer], MAT84)
+            want = amat_expert_matmul_qt(x, own, ul, shift=MAT84.shift)
+            got = amat_expert_matmul_qt(x, qt, ul, layer, shift=MAT84.shift)
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want))
+            outs.append(np.asarray(got))
+        # the periods differ, so a map stuck on one period would show
+        assert not np.allclose(outs[0], outs[1])
+        assert not np.allclose(outs[1], outs[2])
+
+    def test_traced_layer_in_a_scan(self, rng):
+        """The layer index as a scan carries it: a traced scalar."""
+        P, E, M, K, N = 4, 2, 8, 64, 128
+        x = jax.random.normal(rng, (E, M, K))
+        w = jax.random.normal(jax.random.fold_in(rng, 2),
+                              (P, E, K, N)) * 0.1
+        qt = quantize(w, bits=8, group_size=32, asymmetric=True)
+        ul = jnp.array([False, True])
+
+        def body(_, layer):
+            return None, amat_expert_matmul_qt(x, qt, ul, layer, shift=4)
+
+        _, got = jax.lax.scan(body, None, jnp.arange(P))
+        for layer in range(P):
+            ref = amat_batched_matmul_ref(
+                x, qt.codes[layer], qt.scales[layer],
+                qt.zero_points[layer], ul, shift=4)
+            np.testing.assert_allclose(np.asarray(got[layer]),
+                                       np.asarray(ref), atol=1e-4)
 
 
 class TestExpertMatmul:

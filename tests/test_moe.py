@@ -184,3 +184,79 @@ class TestPropertyBased:
         buf = dispatch(x, ids, pos, keep, E, cap)
         y = combine(buf, ids, pos, keep, gates)
         assert float(jnp.max(jnp.abs(y))) <= float(jnp.max(jnp.abs(x))) + 1e-4
+
+
+class TestStackedExpertPeriods:
+    """The period scans hand the MoE the expert codes of every period and
+    the period index (models/model.py ``_scan_periods``).  At tiny widths
+    with three periods of distinct expert weights, prefill and decode with
+    quantized execution must equal a reference whose float expert weights
+    (the same dequantized codes) are sliced per period by the scan
+    itself: an index stuck on one period, or off by one, breaks that."""
+
+    @pytest.fixture(scope="class")
+    def setup(self):
+        from repro.configs.base import get_config
+        from repro.core.slices import quantize_moe_params
+        from repro.models import model as MDL
+        from repro.quant.groupquant import dequantize
+
+        base = get_config("qwen15-moe-repro")
+        cfg = dataclasses.replace(
+            base, n_layers=3, dtype="float32",
+            moe=dataclasses.replace(base.moe, n_experts=8, top_k=2))
+        assert cfg.n_periods == 3
+        params = MDL.init_params(cfg, jax.random.PRNGKey(0))
+        qp, _, _ = quantize_moe_params(params, cfg, MAT84)
+
+        def with_experts(fn):
+            blocks = {}
+            for key, blk in qp["blocks"].items():
+                if "moe" in blk:
+                    ex = blk["moe"]["experts"]
+                    blk = {**blk, "moe": {**blk["moe"], "experts": fn(ex)}}
+                blocks[key] = blk
+            return {**qp, "blocks": blocks}
+
+        ref = with_experts(lambda ex: {"wi": dequantize(ex["wi_q"]),
+                                       "wo": dequantize(ex["wo_q"])})
+        period0 = with_experts(lambda ex: jax.tree.map(
+            lambda a: jnp.broadcast_to(a[:1], a.shape), ex))
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
+                                  cfg.vocab_size)
+        return cfg, qp, ref, period0, toks
+
+    @staticmethod
+    def _serve(cfg, params, toks, quant_execution):
+        """Prefill logits, then the logits of two greedy decode steps."""
+        from repro.models import model as MDL
+
+        logits, cache, _ = MDL.prefill(params, cfg, toks, 16, mat=MAT84,
+                                       quant_execution=quant_execution)
+        out = [logits]
+        for _ in range(2):
+            tok = jnp.argmax(out[-1], -1).astype(jnp.int32)
+            logits, cache, _ = MDL.decode_step(
+                params, cfg, tok, cache, mat=MAT84,
+                quant_execution=quant_execution)
+            out.append(logits)
+        return [np.asarray(o) for o in out]
+
+    @pytest.fixture(scope="class")
+    def runs(self, setup):
+        cfg, qp, ref, period0, toks = setup
+        return {"want": self._serve(cfg, ref, toks, False),
+                "kernel": self._serve(cfg, qp, toks, True),
+                "dense": self._serve(cfg, qp, toks, False),
+                "period0": self._serve(cfg, period0, toks, True)}
+
+    @pytest.mark.parametrize("step", [0, 1, 2], ids=["prefill", "decode1",
+                                                     "decode2"])
+    def test_matches_per_period_reference(self, runs, step):
+        want = runs["want"][step]
+        np.testing.assert_allclose(runs["kernel"][step], want, rtol=1e-5,
+                                   atol=1e-5)
+        # the dense-dequant path indexes the same stacked leaves
+        np.testing.assert_array_equal(runs["dense"][step], want)
+        # every period's experts count: period 0's everywhere is far off
+        assert np.max(np.abs(runs["period0"][step] - want)) > 1e-2
