@@ -22,42 +22,22 @@ import jax
 import numpy as np
 from jax import monitoring
 
-from repro.configs.base import ModelConfig
 from repro.core.amat import MatConfig
 from repro.core.engine import EngineConfig, PersistentEngine
 from repro.core.slices import ExpertSliceStore
-from repro.models.moe import MoECfg, RoutingPolicy
+from repro.models.moe import RoutingPolicy
 from repro.serving.scheduler import (ContinuousBatchingScheduler, Request,
                                      SchedulerConfig)
 
 from chipbench import weights
-from chipbench.cellconfig import dims
+from chipbench.cellconfig import dims, family
 from chipbench.traffic import Traffic, percentile
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 clock = time.perf_counter
 
 
-def model_config(conf: dict) -> ModelConfig:
-    dm = dims(conf)
-    eng = conf["engine"]
-    return ModelConfig(
-        name=conf["name"], arch_type="moe", n_layers=dm["layers"],
-        d_model=dm["d"], n_heads=dm["heads"], n_kv_heads=dm["kv_heads"],
-        head_dim=dm["head_dim"], d_ff=dm["dense_ff"], vocab_size=dm["vocab"],
-        mlp_type="swiglu",
-        moe=MoECfg(n_experts=dm["experts"], top_k=dm["top_k"],
-                   d_ff=dm["expert_ff"],
-                   n_shared_experts=1 if dm["shared_ff"] else 0,
-                   d_ff_shared=dm["shared_ff"],
-                   capacity_factor=eng["capacity_factor"],
-                   mlp_type="swiglu"),
-        rope_theta=dm["rope_theta"], norm_eps=dm["eps"],
-        tie_embeddings=False, qkv_bias=dm["qkv_bias"], dtype="bfloat16",
-        source=conf["source"])
-
-
-def engine_config(conf: dict, cfg: ModelConfig, max_seq: int) -> EngineConfig:
+def engine_config(conf: dict, cfg, max_seq: int) -> EngineConfig:
     eng = conf["engine"]
     mat = MatConfig(*eng["mat_bits"], group_size=eng["group_size"])
     store = ExpertSliceStore.for_config(cfg, mat)
@@ -119,7 +99,7 @@ class BenchEngine(PersistentEngine):
     so its span holds host work only.  Each decode step also records
     which request sat in each slot and its KV length, from the
     scheduler's own slot table, and the routing inputs the policy read:
-    ``alpha`` and each layer's resident experts.
+    ``alpha`` and the resident experts of each MoE pattern position.
     """
 
     def __init__(self, *a, **kw):
@@ -159,7 +139,8 @@ class BenchEngine(PersistentEngine):
             out = super().decode_batch(token, kv_cache, **kw)
         rec["t1"] = clock()
         rec["alpha"] = float(kw.get("alpha", 0.0))
-        rec["resident"] = self._state["pos0"]["cached_msb"]   # [L, E]
+        rec["resident"] = {k: v["cached_msb"]                # [periods, E]
+                           for k, v in self._state.items()}
         self.steps.append(rec)
         return out
 
@@ -188,7 +169,7 @@ class Cell:
     def __init__(self, conf: dict, mix: dict, seed: int):
         self.conf, self.mix, self.seed = conf, mix, seed
         self.dm = dims(conf)
-        self.cfg = model_config(conf)
+        self.cfg = family(self.dm["family"]).model_config(conf, self.dm)
         self.ecfg = engine_config(conf, self.cfg, mix["max_seq"])
         self.traffic = Traffic(mix, self.dm["vocab"], seed)
         self.n_compiles = 0
@@ -323,15 +304,29 @@ class Cell:
     # --------------------------------------------------------- results
     def keep_for_check(self) -> None:
         """Copy out what the comparison reads: the slot table, routing and
-        Cache-Prior boost (``1 + alpha`` on resident experts, [L, E]) of
-        every decode step, each prefill's routing, every request's first
-        token and completion."""
-        self.steps_log = list(self.engine.steps)
-        self.plan_log = [tr + (1.0 + st["alpha"]
-                               * np.asarray(st["resident"], np.float32),)
+        Cache-Prior boost (``1 + alpha`` on resident experts) of every
+        decode step, each prefill's routing, every request's first token
+        and completion.  Routing arrays come as [MoE layer, 1, ...] and
+        the boost as [MoE layer, E], in the program's flat MoE-layer
+        order (period-major over the pattern's MoE positions)."""
+        e = self.engine
+        self.steps_log = list(e.steps)
+
+        def flat(a):
+            return np.asarray(a).reshape((-1, 1) + a.shape[2:])
+
+        def boost(st):
+            res = {k: np.asarray(v) for k, v in st["resident"].items()}
+            out = np.zeros((e.n_moe_layers, e.n_experts), np.float32)
+            for (pos, period), li in e.layer_map.items():
+                out[li] = res[f"pos{pos}"][period]
+            return 1.0 + st["alpha"] * out
+
+        self.plan_log = [tuple(flat(a) for a in tr) + (boost(st),)
                          for tr, st in zip(self.recorder.decode,
                                            self.steps_log)]
-        self.prefill_plan = dict(self.recorder.prefill)
+        self.prefill_plan = {r: flat(a)
+                             for r, a in self.recorder.prefill.items()}
         self.first_tokens = dict(self.engine.first_token)
         self.completions = {c.request_id: c.tokens
                             for c in self.sched.completions}

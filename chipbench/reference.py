@@ -1,15 +1,17 @@
 """Plain float32 reference of a served cell, and the comparison that
 decides ``correct``.
 
-The reference is the published decoder in plain ``jax.numpy``: RMSNorm,
-rotary attention (rotate-half) with optional q/k/v biases, a softmax
-router, SwiGLU experts quantized as the configuration states (AMAT: 8-bit
-asymmetric codes in groups of 32 along the input axis; an MSB-only slice
-keeps the top 4 bits of code and zero-point and scales by 16), an
-ungated shared SwiGLU expert, and an untied unembedding.  Every matmul
-runs at ``Precision.HIGHEST``.  It imports nothing of the program: it
-draws its own weights from the seed (``chipbench.weights``) and
-quantizes them itself.
+The reference is the published decoder in plain ``jax.numpy``.  What
+depends on the decoder family (attention, the layer stack) is the
+family's ``forward`` (``chipbench/families/<family>.py``); this module
+holds what every family shares: RMSNorm, the MoE block (a softmax
+router, SwiGLU experts quantized as the configuration states (AMAT:
+8-bit asymmetric codes in groups of 32 along the input axis; an MSB-only
+slice keeps the top 4 bits of code and zero-point and scales by 16), an
+ungated shared SwiGLU expert), the untied unembedding, the routing plan
+and the comparison.  Every matmul runs at ``Precision.HIGHEST``.  It
+imports nothing of the program: it draws its own weights from the seed
+(``chipbench.weights``) and quantizes them itself.
 
 The served decode is not a plain top-k model: which experts a token uses
 follows the cache-prior policy (top-k of the router probabilities, each
@@ -23,12 +25,14 @@ selections to the configuration's rules with its own router:
 * routing: a served expert's boosted score may lie below the reference's
   k-th best by at most a small share (``route_gap``: bf16 serving swaps
   near ties, a wrong expert lies far below); prefill is plain top-k;
-* precision: a selection whose own gate reaches ``theta`` is critical and
-  needs MSB+LSB; ``msb_gate`` is the largest such gate that the trace
-  says ran MSB-only.  The reference computes every selection at MSB+LSB
-  where the trace or its own gate asks for it, and prefill at MSB+LSB;
+* precision: a selection whose own renormalised gate reaches ``theta``
+  is critical and needs MSB+LSB; ``msb_gate`` is the largest such gate
+  that the trace says ran MSB-only.  The reference computes every
+  selection at MSB+LSB where the trace or its own gate asks for it, and
+  prefill at MSB+LSB;
 * the gates: its own router probabilities at the served ids,
-  renormalised;
+  renormalised where the configuration's ``norm_topk`` says so, raw
+  otherwise (criticality reads the renormalised ones either way);
 * capacity dispatch: per expert, at most ``capacity(T)`` token slots over
   the step's whole batch, filled in k-slot order then token order; the
   rest contribute nothing.
@@ -45,7 +49,6 @@ the same prompt and served tokens.
 
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
@@ -53,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import weights
+from chipbench.cellconfig import family
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -91,19 +95,23 @@ def sequence_plan(dm: dict, prefill_ids: np.ndarray, steps: list,
                   max_batch: int):
     """Routing plan of one request over its prompt and decode positions.
 
-    ``prefill_ids``: [L, 1, P, k]; ``steps``: one ``(ids, active,
-    critical, slot, boost)`` per decode step (arrays [L, 1, B, k];
-    ``boost`` [L, E], the Cache-Prior factor of each expert).  Returns
-    ``ids, keep, hi`` of shape [L, P + len(steps), k], ``boost`` of shape
-    [L, P + len(steps), E] (1 in prefill), and the number of the
-    request's own selections that the trace reports out of range or
-    inactive.  Under top-k and Cache-Prior routing every selection of a
-    live sequence is a real, active expert, so that count must be 0: a
-    slot the program left out of a step shows up here, not as a plan
-    the reference would follow.
+    ``L`` counts the MoE layers (``dm["moe_layers"]``): a dense layer
+    takes no routing.  ``prefill_ids``: [L, 1, P, k]; ``steps``: one
+    ``(ids, active, critical, slot, boost)`` per decode step (arrays
+    [L, 1, B, k]; ``boost`` [L, E], the Cache-Prior factor of each
+    expert).  Returns ``ids, keep, hi`` of shape [L, P + len(steps), k],
+    ``boost`` of shape [L, P + len(steps), E] (1 in prefill), and the
+    number of the request's own selections that the trace reports out of
+    range or inactive.  Under top-k and Cache-Prior routing every
+    selection of a live sequence is a real, active expert, so that count
+    must be 0: a slot the program left out of a step shows up here, not
+    as a plan the reference would follow.
     """
     E, k, f = dm["experts"], dm["top_k"], dm["capacity_factor"]
-    L, P = prefill_ids.shape[0], prefill_ids.shape[2]
+    L, P = len(dm["moe_layers"]), prefill_ids.shape[2]
+    if prefill_ids.shape[0] != L:
+        raise ValueError(f"routing of {prefill_ids.shape[0]} layers for "
+                         f"{L} MoE layers")
     n = P + len(steps)
     ids = np.zeros((L, n, k), np.int32)
     keep = np.zeros((L, n, k), bool)
@@ -130,25 +138,18 @@ def sequence_plan(dm: dict, prefill_ids: np.ndarray, steps: list,
 
 
 # ------------------------------------------------------------- arithmetic
-def _mm(a, b, lowp: bool):
+def mm(a, b, lowp: bool):
+    """``a @ b`` in float32 at HIGHEST; with ``lowp`` both operands are
+    first rounded to float8 (the control)."""
     if lowp:
         a = a.astype(jnp.float8_e4m3fn).astype(jnp.float32)
         b = b.astype(jnp.float8_e4m3fn).astype(jnp.float32)
     return jnp.matmul(a, b, precision=HI)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
     return x * (1.0 + scale.astype(jnp.float32))
-
-
-def _rope(x, pos, theta):
-    d = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos[:, None].astype(jnp.float32) * inv
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = jnp.split(x, 2, -1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
 def _quant(w, dm):
@@ -169,64 +170,33 @@ def _quant(w, dm):
     return w_hi.reshape(K, N), w_lo.reshape(K, N)
 
 
-def _swiglu(h, wi, wo, lowp):
-    g, u = jnp.split(_mm(h, wi, lowp), 2, -1)
-    return _mm(jax.nn.silu(g) * u, wo, lowp)
+def swiglu(h, wi, wo, lowp):
+    g, u = jnp.split(mm(h, wi, lowp), 2, -1)
+    return mm(jax.nn.silu(g) * u, wo, lowp)
 
 
-@partial(jax.jit, static_argnames=("dm", "lowp", "lo_rows"))
-def _layer(x, blk, li, ids, keep, hi, boost, n_valid, lo_at, *, dm, lowp,
-           lo_rows):
-    """One decoder layer over a padded sequence.  Returns the new
-    residual stream, and for each position the widest ``route_gap`` and
-    ``msb_gate`` of its selections (0 on padding).  Only the ``lo_rows``
-    rows from ``lo_at`` on (the decode positions) may run MSB-only."""
-    dm = dict(dm)
-    p = jax.tree_util.tree_map(lambda a: a[li], blk)
-    S = x.shape[0]
-    H, KV, hd = dm["heads"], dm["kv_heads"], dm["head_dim"]
+def moe_block(x, p, ids, keep, hi, boost, lo_at, *, dm, lowp, lo_rows):
+    """The MoE half of one layer over a padded sequence, with its norm:
+    ``p`` holds the layer's ``moe_norm`` and ``moe`` leaves.  Returns the
+    block's output (without the residual) and, for each position, the
+    widest ``route_gap`` and ``msb_gate`` of its selections.  Only the
+    ``lo_rows`` rows from ``lo_at`` on (the decode positions) may run
+    MSB-only.  Called inside each family's jitted layer."""
     f32 = jnp.float32
-    pos = jnp.arange(S)
-    h = _rms(x, p["norm"], dm["eps"])
-    q = _mm(h, p["wq"].astype(f32), lowp)
-    k = _mm(h, p["wk"].astype(f32), lowp)
-    v = _mm(h, p["wv"].astype(f32), lowp)
-    if dm["qkv_bias"]:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = _rope(q.reshape(S, H, hd), pos, dm["rope_theta"])
-    k = _rope(k.reshape(S, KV, hd), pos, dm["rope_theta"])
-    v = v.reshape(S, KV, hd)
-    rep = H // KV
-    k = jnp.repeat(k, rep, axis=1)
-    v = jnp.repeat(v, rep, axis=1)
-    if lowp:
-        q, k, v = (t.astype(jnp.float8_e4m3fn).astype(f32) for t in (q, k, v))
-    valid = pos < n_valid
-
-    def attend(qb_pos):
-        qb, pb = qb_pos
-        sc = jnp.einsum("qhd,khd->hqk", qb, k, precision=HI) * hd ** -0.5
-        mask = (pb[:, None] >= pos[None, :]) & valid[None, :]
-        pr = jax.nn.softmax(jnp.where(mask[None], sc, -1e30), -1)
-        if lowp:
-            pr = pr.astype(jnp.float8_e4m3fn).astype(f32)
-        return jnp.einsum("hqk,khd->qhd", pr, v, precision=HI)
-
-    blk_q = math.gcd(S, 256)
-    o = jax.lax.map(attend, (q.reshape(S // blk_q, blk_q, H, hd),
-                             pos.reshape(S // blk_q, blk_q)))
-    x = x + _mm(o.reshape(S, H * hd), p["wo"].astype(f32), lowp)
-
-    h = _rms(x, p["moe_norm"], dm["eps"])
-    probs = jax.nn.softmax(_mm(h, p["moe"]["w_router"].astype(f32), lowp), -1)
+    h = rms(x, p["moe_norm"], dm["eps"])
+    probs = jax.nn.softmax(mm(h, p["moe"]["w_router"].astype(f32), lowp), -1)
     boosted = probs * boost
     kth = jax.lax.top_k(boosted, dm["top_k"])[0][:, -1:]
     route_gap = jnp.max(jnp.maximum(
         0.0, 1.0 - jnp.take_along_axis(boosted, ids, -1) / kth), -1)
-    g = jnp.take_along_axis(probs, ids, -1)
-    g = g / jnp.maximum(g.sum(-1, keepdims=True), 1e-9)
+    raw = jnp.take_along_axis(probs, ids, -1)
+    g = raw / jnp.maximum(raw.sum(-1, keepdims=True), 1e-9)
+    # Criticality reads the renormalised gates whatever weights the
+    # outputs (the paper's rule).
     msb_gate = jnp.max(jnp.where(hi > 0, 0.0, g), -1)
     hi = jnp.maximum(hi, (g >= dm["theta"]).astype(f32))
+    if not dm["norm_topk"]:
+        g = raw
     g = g * keep
     onehot = jax.nn.one_hot(ids, dm["experts"], dtype=f32)      # [S, k, E]
     c_hi = jnp.einsum("sk,ske->es", g * hi, onehot)
@@ -246,8 +216,8 @@ def _layer(x, blk, li, ids, keep, hi, boost, n_valid, lo_at, *, dm, lowp,
         wi, wo, ch, cl = xs
         wi_h, wi_l = _quant(wi, dm)
         wo_h, wo_l = _quant(wo, dm)
-        y = y + ch[:, None] * _swiglu(h, wi_h, wo_h, lowp)
-        y_lo = y_lo + cl[:, None] * _swiglu(h_lo, wi_l, wo_l, lowp)
+        y = y + ch[:, None] * swiglu(h, wi_h, wo_h, lowp)
+        y_lo = y_lo + cl[:, None] * swiglu(h_lo, wi_l, wo_l, lowp)
         return (y, y_lo), None
 
     ex = p["moe"]["experts"]
@@ -258,16 +228,15 @@ def _layer(x, blk, li, ids, keep, hi, boost, n_valid, lo_at, *, dm, lowp,
         y, jax.lax.dynamic_slice_in_dim(y, lo_at, lo_rows) + y_lo, lo_at, 0)
     if "shared" in p["moe"]:
         sh = p["moe"]["shared"]
-        y = y + _swiglu(h, sh["wi"].astype(f32), sh["wo"].astype(f32), lowp)
-    return (x + y, jnp.where(valid, route_gap, 0.0),
-            jnp.where(valid, msb_gate, 0.0))
+        y = y + swiglu(h, sh["wi"].astype(f32), sh["wo"].astype(f32), lowp)
+    return y, route_gap, msb_gate
 
 
 @partial(jax.jit, static_argnames=("dm", "lowp"))
 def _head(x, params, qpos, *, dm, lowp):
     dm = dict(dm)
-    h = _rms(x[qpos], params["final_norm"], dm["eps"])
-    return _mm(h, params["unembed"].astype(jnp.float32), lowp)
+    h = rms(x[qpos], params["final_norm"], dm["eps"])
+    return mm(h, params["unembed"].astype(jnp.float32), lowp)
 
 
 class Reference:
@@ -276,6 +245,7 @@ class Reference:
     def __init__(self, dm: dict, seed: int, seq_pad: int, query_pad: int):
         self.dm = dm
         self.frozen = tuple(sorted(dm.items()))
+        self.family = family(dm["family"])
         self.params = weights.draw(dm, seed)
         self.seq_pad, self.query_pad = seq_pad, query_pad
 
@@ -301,14 +271,9 @@ class Reference:
         qpos[:n] = np.arange(P - 1, P - 1 + n)
         p = self.params
         x = p["embed"][jnp.asarray(tokens)].astype(jnp.float32)
-        route = msb = 0.0
-        for li in range(self.dm["layers"]):
-            x, rg, mg = _layer(x, p["blocks"]["pos0"], li, ids[li], keep[li],
-                               hi[li].astype(jnp.float32), boost[li],
-                               jnp.int32(len(fed)), jnp.int32(P),
-                               dm=self.frozen, lowp=lowp, lo_rows=Q)
-            route = max(route, float(rg.max()))
-            msb = max(msb, float(mg.max()))
+        x, route, msb = self.family.forward(
+            p["blocks"], x, (ids, keep, hi, boost), jnp.int32(len(fed)),
+            jnp.int32(P), dm=self.frozen, lowp=lowp, lo_rows=Q)
         return _head(x, p, jnp.asarray(qpos), dm=self.frozen, lowp=lowp), \
             route, msb
 

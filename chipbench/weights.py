@@ -1,20 +1,20 @@
 """Seeded random weights of a cell, drawn on the device in one jitted call.
 
-The tree has the layout the program's MoE decoder takes (one repeating
-attention + MoE block, leaves stacked over layers, untied unembedding),
-in bf16, the type it serves.  A vector (a norm's scale, a q/k/v bias;
-one per layer where stacked) is normal with standard deviation
-``VECTOR_STD``, so a program that drops a bias or misapplies a norm gives
-other logits; the embedding is standard normal; every other leaf is
-normal with standard deviation ``fan_in ** -0.5`` (``fan_in`` = the
-second-to-last dimension), so every layer's output keeps unit scale.
-The embedding departs from the program's own initialiser, which scales
-it by ``vocab ** -0.5``: rows that small leave the residual stream to the
-attention output, which at long prompts is nearly the same average for
-every position, and the router then sends most of a prompt's tokens to
-the same few experts.  Unit rows keep routing a function of the token,
-as in a trained model.  The reference draws the same tree from the same
-seed.
+The tree has the layout the program takes for the cell's decoder family
+(the family's ``param_shapes``: leaves under ``blocks`` stacked over
+layers, an untied unembedding), in bf16, the type it serves. A vector (a
+norm's scale, a q/k/v bias; one per layer where stacked) is normal with
+standard deviation ``VECTOR_STD``, so a program that drops a bias or
+misapplies a norm gives other logits; the embedding is standard normal;
+every other leaf is normal with standard deviation ``fan_in ** -0.5``
+(``fan_in`` = the second-to-last dimension), so every layer's output
+keeps unit scale. The embedding departs from the program's own
+initialiser, which scales it by ``vocab ** -0.5``: rows that small leave
+the residual stream to the attention output, which at long prompts is
+nearly the same average for every position, and the router then sends
+most of a prompt's tokens to the same few experts. Unit rows keep
+routing a function of the token, as in a trained model. The reference
+draws the same tree from the same seed.
 """
 
 from __future__ import annotations
@@ -24,25 +24,21 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from chipbench.cellconfig import family
+
 VECTOR_STD = 0.5
 
 
-def param_shapes(dm: dict) -> dict:
-    d, L, E = dm["d"], dm["layers"], dm["experts"]
-    q = dm["heads"] * dm["head_dim"]
-    kv = dm["kv_heads"] * dm["head_dim"]
+def moe_shapes(dm: dict, n: int) -> dict:
+    """The MoE block's leaves, stacked over ``n`` layers: router, routed
+    SwiGLU experts, and the shared expert where there is one."""
+    d, E = dm["d"], dm["experts"]
     F, Fs = dm["expert_ff"], dm["shared_ff"]
-    blk = {"wq": (L, d, q), "wk": (L, d, kv), "wv": (L, d, kv),
-           "wo": (L, q, d), "norm": (L, d), "moe_norm": (L, d)}
-    if dm["qkv_bias"]:
-        blk.update(bq=(L, q), bk=(L, kv), bv=(L, kv))
-    moe = {"w_router": (L, d, E),
-           "experts": {"wi": (L, E, d, 2 * F), "wo": (L, E, F, d)}}
+    moe = {"w_router": (n, d, E),
+           "experts": {"wi": (n, E, d, 2 * F), "wo": (n, E, F, d)}}
     if Fs:
-        moe["shared"] = {"wi": (L, d, 2 * Fs), "wo": (L, Fs, d)}
-    blk["moe"] = moe
-    return {"embed": (dm["vocab"], d), "blocks": {"pos0": blk},
-            "final_norm": (d,), "unembed": (d, dm["vocab"])}
+        moe["shared"] = {"wi": (n, d, 2 * Fs), "wo": (n, Fs, d)}
+    return moe
 
 
 def _is_shape(x) -> bool:
@@ -90,4 +86,5 @@ def _thaw(frozen):
 
 def draw(dm: dict, seed: int) -> dict:
     """The cell's bf16 weights for ``seed``, made on the default device."""
-    return _draw(seed_key(seed), _freeze(param_shapes(dm)))
+    return _draw(seed_key(seed),
+                 _freeze(family(dm["family"]).param_shapes(dm)))

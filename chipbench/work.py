@@ -13,10 +13,11 @@ and a zero-point of the code's width per group of ``group_size``; plus
 each routed pair's activations in bf16 (input and output of both
 projections) and its FLOPs.
 
-Whole decode step: the expert layer, plus the bf16 weights of attention,
-router, shared expert, norms and unembedding, the embedding rows and
-KV rows (bf16, keys and values) of every valid position of each active
-sequence, and the FLOPs of every matmul and of attention over those rows.
+Whole decode step: the expert layer, plus the rest of the step as the
+cell's decoder family counts it (its ``dense_work``: the bf16 weights
+outside the routed experts, the embedding rows, the cached rows of every
+valid position of each active sequence, and the FLOPs of every matmul
+and of attention over those rows).
 """
 
 from __future__ import annotations
@@ -25,6 +26,8 @@ import json
 import os
 
 import numpy as np
+
+from chipbench.cellconfig import family
 
 BF16 = 2
 
@@ -72,19 +75,9 @@ def expert_work(dm: dict, ids, active, critical) -> tuple[float, float]:
 
 def step_work(dm: dict, ids, active, critical,
               kv_len) -> tuple[float, float]:
-    """(bytes, FLOPs) of a whole decode step; ``kv_len`` holds each
+    """(bytes, FLOPs) of a whole decode step: the family's
+    ``dense_work`` plus :func:`expert_work`.  ``kv_len`` holds each
     active sequence's valid KV rows after the step's write."""
-    d, V, L = dm["d"], dm["vocab"], dm["layers"]
-    H, KV, hd = dm["heads"], dm["kv_heads"], dm["head_dim"]
-    E, Fs = dm["experts"], dm["shared_ff"]
-    B = len(kv_len)
-    rows = float(sum(kv_len))
-    attn_w = d * (H + 2 * KV) * hd + H * hd * d
-    dense_w = attn_w + d * E + 3 * d * Fs
-    nbytes, flops = expert_work(dm, ids, active, critical)
-    per_layer_bytes = (dense_w + 2 * d + (H + 2 * KV) * hd) * BF16 \
-        + rows * 2 * KV * hd * BF16
-    nbytes += L * per_layer_bytes + (d * V + d + B * d) * BF16
-    flops += L * (2.0 * B * dense_w + 4.0 * rows * H * hd)
-    flops += 2.0 * B * d * V
-    return nbytes, flops
+    eb, ef = expert_work(dm, ids, active, critical)
+    db, df = family(dm["family"]).dense_work(dm, kv_len)
+    return eb + db, ef + df

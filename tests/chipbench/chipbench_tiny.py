@@ -4,6 +4,8 @@ and the chat mix, shrunk to sizes Pallas interpret mode runs in seconds."""
 import os
 import sys
 
+import numpy as np
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 for p in (ROOT, os.path.join(ROOT, "src")):
@@ -43,3 +45,25 @@ def bench() -> dict:
                  "step_mfu.decode")
     return {"end_to_end": [{"name": n, "unit": "u"} for n in e2e],
             "per_layer": [{"name": n, "unit": "u"} for n in per_layer]}
+
+
+def plan(dm, rng, n_layers, P=6, B=3, n_steps=2):
+    """A routing plan of one request: a prefill of ``P`` and ``n_steps``
+    decode steps in slot 1 of ``B``, drawn from ``rng``."""
+    E, k = dm["experts"], dm["top_k"]
+
+    def pick(rows):
+        return np.stack([np.stack([rng.permutation(E)[:k]
+                                   for _ in range(rows)])[None]
+                         for _ in range(n_layers)]).astype(np.int32)
+
+    prefill = pick(P)
+    steps = []
+    for _ in range(n_steps):
+        sid = pick(B)
+        act = np.ones((n_layers, 1, B, k), bool)
+        crit = rng.random((n_layers, 1, B, k)) < 0.5
+        boost = (1.0 + 0.3 * (rng.random((n_layers, E)) < 0.5)) \
+            .astype(np.float32)
+        steps.append((sid, act, crit, 1, boost))
+    return prefill, steps

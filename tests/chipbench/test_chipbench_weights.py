@@ -5,16 +5,16 @@ import jax
 import jax.numpy as jnp
 
 from chipbench import weights
-from chipbench.cellconfig import dims
-from chipbench.harness import model_config
+from chipbench.cellconfig import dims, family
 from repro.models.model import init_params
 
 
 def test_tree_matches_init_params_at_tiny_size():
     conf = chipbench_tiny.conf()
-    ours = jax.eval_shape(lambda: weights.draw(dims(conf), 5))
-    theirs = jax.eval_shape(lambda: init_params(model_config(conf),
-                                                jax.random.PRNGKey(0)))
+    dm = dims(conf)
+    ours = jax.eval_shape(lambda: weights.draw(dm, 5))
+    cfg = family(dm["family"]).model_config(conf, dm)
+    theirs = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     shape = lambda t: jax.tree_util.tree_map(lambda a: a.shape, t)  # noqa: E731
     assert jax.tree_util.tree_structure(ours) == \
         jax.tree_util.tree_structure(theirs)

@@ -6,9 +6,10 @@ import pytest
 
 from chipbench.work import expert_work, least_time, peaks, step_work
 
-DM = {"d": 64, "expert_ff": 32, "experts": 4, "group_size": 32,
-      "high_bits": 8, "low_bits": 4, "vocab": 100, "layers": 1,
-      "heads": 2, "kv_heads": 2, "head_dim": 32, "shared_ff": 16}
+DM = {"family": "mha_moe", "d": 64, "expert_ff": 32, "experts": 4,
+      "group_size": 32, "high_bits": 8, "low_bits": 4, "vocab": 100,
+      "layers": 1, "heads": 2, "kv_heads": 2, "head_dim": 32,
+      "shared_ff": 16}
 
 
 def _step():
