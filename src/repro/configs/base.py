@@ -11,6 +11,7 @@ import dataclasses
 import importlib
 from typing import Optional, Tuple
 
+from repro.models.layers import YarnScaling
 from repro.models.moe import MoECfg
 from repro.models.ssm import SSMCfg
 
@@ -41,6 +42,21 @@ class ModelConfig:
     pattern: Optional[Tuple[BlockSpec, ...]] = None
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
+    # Attention kind: 'mha' (multi-head/grouped-query, the default) or
+    # 'mla' (DeepSeek-V2 latent attention: queries of qk_nope + qk_rope
+    # per head, keys and values up-projected from a kv_lora_rank latent
+    # with one rope'd key part shared by all heads; the decode cache
+    # holds the latent and that key part).
+    attn_kind: str = "mha"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[YarnScaling] = None
+    # Leading layers of attention + dense MLP (d_ff) that run once before
+    # the repeating pattern, with their own parameters and cache
+    # (``blocks/prefix``); n_layers counts them.
+    first_k_dense: int = 0
     # Sliding-window attention width used for the long-context decode shape
     # (and, if ``always_swa``, everywhere).
     sliding_window: Optional[int] = None
@@ -107,11 +123,13 @@ class ModelConfig:
     @property
     def n_periods(self) -> int:
         plen = len(self.block_pattern)
-        if self.n_layers % plen != 0:
+        n = self.n_layers - self.first_k_dense
+        if n % plen != 0:
             raise ValueError(
-                f"{self.name}: n_layers={self.n_layers} not divisible by "
+                f"{self.name}: n_layers={self.n_layers} less "
+                f"first_k_dense={self.first_k_dense} not divisible by "
                 f"pattern length {plen}")
-        return self.n_layers // plen
+        return n // plen
 
     @property
     def has_attention(self) -> bool:

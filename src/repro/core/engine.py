@@ -319,6 +319,8 @@ class PersistentEngine:
         # Wall-clock spans of the host's work (repro.obs.hosttrace); the
         # scheduler shares this instance.  Never read by the charge path.
         self.host_trace = HostTrace()
+        # Bytes of the batch KV cache, once init_batch_cache made one.
+        self.kv_cache_bytes: Optional[int] = None
 
         # moe pattern positions in order (matches aux stacking order)
         self.moe_positions = [i for i, s in enumerate(cfg.block_pattern)
@@ -484,9 +486,12 @@ class PersistentEngine:
         return MissRateController(self.ecfg.miss_rate_target)
 
     def init_batch_cache(self, max_batch: int) -> dict:
-        """Batched KV-cache pytree with per-sequence positions."""
+        """Batched KV-cache pytree with per-sequence positions; its bytes
+        are kept as ``kv_cache_bytes``."""
         cache = MDL.init_cache(self.cfg, max_batch, self.ecfg.max_seq)
         cache["pos"] = jnp.zeros((max_batch,), jnp.int32)
+        self.kv_cache_bytes = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(cache))
         return cache
 
     @staticmethod

@@ -15,4 +15,7 @@ Subpackages:
   * :mod:`repro.kernels.expert_matmul` — the original batched per-expert
     sliced dequant matmul (per-expert flag as a VMEM block).
   * :mod:`repro.kernels.flash_attn` — blockwise online-softmax attention.
+  * :mod:`repro.kernels.mla_decode` — latent-attention decode over a
+    latent KV cache (DeepSeek-V2's absorbed MLA), the served decode path
+    of ``attn_kind="mla"``.
 """

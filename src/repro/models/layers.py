@@ -6,7 +6,8 @@ dry-run can lower every (arch x input-shape) pair without allocation.
 
 Covers the assigned architecture pool:
   * RMSNorm / LayerNorm
-  * RoPE (configurable theta, partial-dim for Mamba-hybrids)
+  * RoPE (configurable theta, partial-dim for Mamba-hybrids), and YaRN
+    frequency scaling with DeepSeek-V2's interleaved rope pairs
   * GQA attention with optional sliding window and logit soft-capping,
     causal or full (encoder), plus cross-attention (whisper)
   * Blockwise ("flash-style") attention via lax.scan over KV chunks, used
@@ -20,6 +21,7 @@ Covers the assigned architecture pool:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
@@ -72,6 +74,75 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """``g(s, mu) = 0.1 * mu * ln(s) + 1`` (1 for ``s <= 1``)."""
+    return 0.1 * mscale * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, ys: YarnScaling) -> jax.Array:
+    """Inverse frequencies of ``dim`` rope dims under YaRN: the unscaled
+    frequency below the correction range, the frequency over ``factor``
+    above it, and a linear ramp between."""
+    def correction_dim(rotations):
+        return dim * math.log(ys.original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(correction_dim(ys.beta_fast)), 0)
+    hi = min(math.ceil(correction_dim(ys.beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    extra = rope_frequencies(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / ys.factor * (1.0 - keep) + extra * keep
+
+
+def yarn_attention_factor(ys: YarnScaling) -> float:
+    """The factor YaRN puts on cos and sin."""
+    return yarn_mscale(ys.factor, ys.mscale) \
+        / yarn_mscale(ys.factor, ys.mscale_all_dim)
+
+
+def mla_softmax_scale(qk_head_dim: int,
+                      ys: Optional[YarnScaling]) -> float:
+    """Latent attention's softmax scale: ``qk_head_dim ** -0.5``, times
+    ``g(factor, mscale_all_dim) ** 2`` under YaRN (DeepSeek-V2)."""
+    scale = qk_head_dim ** -0.5
+    if ys is not None and ys.mscale_all_dim:
+        scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
+                           inv_freq: jax.Array,
+                           factor: float = 1.0) -> jax.Array:
+    """RoPE over rope pairs stored interleaved (DeepSeek-V2): the last
+    axis of x [..., S, H, D] is de-interleaved (even dims, then odd
+    ones) and rotated half against half; the result stays de-interleaved.
+    ``factor`` scales cos and sin."""
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = (jnp.cos(angles) * factor)[..., None, :]
+    sin = (jnp.sin(angles) * factor)[..., None, :]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
 # --------------------------------------------------------------------------
 # Attention
 # --------------------------------------------------------------------------
@@ -110,17 +181,23 @@ def attention(
     q_offset: int | jax.Array = 0,
     sliding_window: Optional[int] = None,
     logit_softcap: Optional[float] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Multi-head attention; dispatches to blockwise above the threshold."""
+    """Multi-head attention; dispatches to blockwise above the threshold.
+
+    ``v`` may be narrower than ``q``/``k`` (latent attention's expanded
+    form); ``scale`` defaults to ``q.shape[-1] ** -0.5``."""
     if k.shape[1] > BLOCKWISE_THRESHOLD:
         return blockwise_attention(
             q, k, v, causal=causal, q_offset=q_offset,
-            sliding_window=sliding_window, logit_softcap=logit_softcap)
+            sliding_window=sliding_window, logit_softcap=logit_softcap,
+            scale=scale)
 
     dims_rep = q.shape[2] // k.shape[2]
     k = _expand_kv(k, dims_rep)
     v = _expand_kv(v, dims_rep)
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     scores = _soft_cap(scores, logit_softcap)
@@ -146,6 +223,7 @@ def blockwise_attention(
     sliding_window: Optional[int] = None,
     logit_softcap: Optional[float] = None,
     block_kv: int = BLOCK_KV,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Online-softmax attention: lax.scan over KV blocks.
 
@@ -162,9 +240,10 @@ def blockwise_attention(
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     n_blocks = (sk + pad) // block_kv
     kb = k.reshape(b, n_blocks, block_kv, k.shape[2], d)
-    vb = v.reshape(b, n_blocks, block_kv, v.shape[2], d)
+    vb = v.reshape(b, n_blocks, block_kv, v.shape[2], v.shape[-1])
 
-    scale = d ** -0.5
+    if scale is None:
+        scale = d ** -0.5
     qf = q.astype(jnp.float32)
     qpos = jnp.arange(sq) + q_offset
 
@@ -191,7 +270,7 @@ def blockwise_attention(
 
     m0 = jnp.full((b, h, sq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
-    acc0 = jnp.zeros((b, h, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, h, sq, v.shape[-1]), jnp.float32)
     kb_t = jnp.moveaxis(kb, 1, 0)
     vb_t = jnp.moveaxis(vb, 1, 0)
     (m, l, acc), _ = jax.lax.scan(

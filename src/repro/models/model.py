@@ -36,6 +36,9 @@ from repro.models import moe as M
 from repro.models import ssm as S
 
 LOSS_CHUNKS = 16
+# The leading dense layers (``cfg.first_k_dense``, params and cache under
+# ``prefix``) run before the scanned pattern.
+PREFIX_SPEC = BlockSpec("attn", "dense")
 
 
 def _dt(cfg: ModelConfig):
@@ -64,9 +67,24 @@ def _attn_shapes(cfg: ModelConfig, cross: bool = False) -> dict:
     return sh
 
 
+def _mla_shapes(cfg: ModelConfig) -> dict:
+    """Latent attention: the query projection (no query latent), the
+    down-projection to the kv latent and the rope'd key part, the
+    latent's norm, its up-projection to each head's [k_nope | v], and the
+    output projection."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+        cfg.v_head_dim
+    return {"wq": (d, h * (nope + rope)), "wkv_a": (d, r + rope),
+            "kv_a_norm": (r,), "wkv_b": (r, h * (nope + vd)),
+            "wo": (h * vd, d), "norm": (d,)}
+
+
 def _block_shapes(cfg: ModelConfig, spec: BlockSpec, decoder: bool) -> dict:
     sh: dict = {}
-    if spec.mixer == "attn":
+    if spec.mixer == "attn" and cfg.attn_kind == "mla":
+        sh.update(_mla_shapes(cfg))
+    elif spec.mixer == "attn":
         sh.update(_attn_shapes(cfg))
         if decoder and cfg.is_encdec:
             sh.update(_attn_shapes(cfg, cross=True))
@@ -100,6 +118,9 @@ def param_shapes(cfg: ModelConfig) -> dict:
                          cfg.n_periods)
         for i, spec in enumerate(cfg.block_pattern)
     }
+    if cfg.first_k_dense:
+        blocks["prefix"] = stack(
+            _block_shapes(cfg, PREFIX_SPEC, decoder=True), cfg.first_k_dense)
     v_embed = cfg.padded_vocab if cfg.tie_embeddings else cfg.vocab_size
     sh = {
         "embed": (v_embed, cfg.d_model),
@@ -180,9 +201,110 @@ def _attn_qkv(p: dict, x: jax.Array, cfg: ModelConfig, prefix: str = ""):
     return q, k, v
 
 
+def _rope_freqs(cfg: ModelConfig):
+    """(inverse frequencies, cos/sin factor) of latent attention's rope
+    dims: YaRN where the config scales rope, else plain."""
+    dim = cfg.qk_rope_head_dim
+    if cfg.rope_scaling is None:
+        return L.rope_frequencies(dim, cfg.rope_theta), 1.0
+    return (L.yarn_inv_freq(dim, cfg.rope_theta, cfg.rope_scaling),
+            L.yarn_attention_factor(cfg.rope_scaling))
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    return L.mla_softmax_scale(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                               cfg.rope_scaling)
+
+
+def _mla_qkv(p: dict, h: jax.Array, cfg: ModelConfig, positions):
+    """Latent attention's projections of the normed input h [B, S, d]:
+    the queries [B, S, H, nope + rope] with the rope part rotated, and
+    the cache rows [B, S, rank + rope]: the normed latent ``c`` and the
+    rotated key part ``k_pe`` that every head shares."""
+    b, s, _ = h.shape
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    inv, fac = _rope_freqs(cfg)
+    q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, -1)
+    a = h @ p["wkv_a"]
+    c = L.rms_norm(a[..., :r], p["kv_a_norm"], cfg.norm_eps)
+    k_pe = L.apply_rope_interleaved(a[..., None, r:], positions, inv, fac)
+    q_pe = L.apply_rope_interleaved(q[..., nope:], positions, inv, fac)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    return q, jnp.concatenate([c, k_pe[..., 0, :]], axis=-1)
+
+
+def _mla_expand(p: dict, ckv: jax.Array, cfg: ModelConfig):
+    """Keys [B, S, H, nope + rope] and values [B, S, H, v] of latent rows
+    [B, S, >= rank + rope]: each head's [k_nope | v] up-projected from the
+    latent, and the one rope'd key part broadcast to every head."""
+    b, s, _ = ckv.shape
+    r, nope, h = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.n_heads
+    rope = cfg.qk_rope_head_dim
+    kv = (ckv[..., :r] @ p["wkv_b"]).reshape(b, s, h, -1)
+    k_pe = jnp.broadcast_to(ckv[..., None, r:r + rope], (b, s, h, rope))
+    return jnp.concatenate([kv[..., :nope], k_pe], axis=-1), kv[..., nope:]
+
+
+def _pad_last(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad the last axis of ``x`` to ``width``."""
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])]
+    return jnp.pad(x, pad)
+
+
+def _mla_attn_block(p: dict, x: jax.Array, cfg: ModelConfig, *,
+                    positions: jax.Array):
+    """Causal latent attention over a whole sequence, in the expanded
+    form (keys and values up-projected).  Returns the new residual and
+    the sequence's cache rows."""
+    with jax.named_scope("mla.prefill"):
+        h = L.rms_norm(x, p["norm"], cfg.norm_eps)
+        q, ckv = _mla_qkv(p, h, cfg, positions)
+        k, v = _mla_expand(p, ckv, cfg)
+        o = L.attention(q, k, v, causal=True, scale=_mla_scale(cfg))
+        o = o.reshape(x.shape[0], x.shape[1], -1) @ p["wo"]
+        return x + o, ckv
+
+
+def _mla_decode_block(p: dict, x: jax.Array, cfg: ModelConfig,
+                      ckv_cache: jax.Array, *, pos, positions, vector_pos):
+    """One token per sequence of latent attention in the absorbed form:
+    the key up-projection is folded into the query (``q_lat``), the
+    latent cache is attended as multi-query attention by
+    ``mla_decode_attention``, and the value up-projection is applied to
+    its output.  Returns the new residual and cache."""
+    from repro.kernels.mla_decode import mla_decode_attention
+
+    with jax.named_scope("mla.decode"):
+        b = x.shape[0]
+        r, nope, h = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.n_heads
+        hn = L.rms_norm(x, p["norm"], cfg.norm_eps)
+        q, row = _mla_qkv(p, hn, cfg, positions)
+        row = _pad_last(row, ckv_cache.shape[-1])
+        if vector_pos:
+            cache = ckv_cache.at[jnp.arange(b), pos].set(
+                row[:, 0].astype(ckv_cache.dtype))
+        else:
+            cache = jax.lax.dynamic_update_slice(
+                ckv_cache, row.astype(ckv_cache.dtype), (0, pos, 0))
+        w = p["wkv_b"].reshape(r, h, -1).astype(jnp.float32)
+        q = q[:, 0].astype(jnp.float32)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :nope], w[..., :nope])
+        lengths = jnp.broadcast_to(pos + 1, (b,))
+        o_lat = mla_decode_attention(
+            jnp.concatenate([q_lat, q[..., nope:]], axis=-1), cache,
+            lengths, scale=_mla_scale(cfg), rank=r)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat, w[..., nope:]).astype(x.dtype)
+        return x + (o.reshape(b, -1) @ p["wo"])[:, None, :], cache
+
+
 def _self_attn_block(p: dict, x: jax.Array, cfg: ModelConfig, *,
                      causal: bool, positions: jax.Array,
                      window: Optional[int]):
+    """Self-attention with its norm and residual.  Returns the new
+    residual and what the cache keeps: ``(k, v)``, or the latent rows
+    under latent attention."""
+    if cfg.attn_kind == "mla":
+        return _mla_attn_block(p, x, cfg, positions=positions)
     with jax.named_scope("attn"):
         h = L.rms_norm(x, p["norm"], cfg.norm_eps)
         q, k, v = _attn_qkv(p, h, cfg)
@@ -251,6 +373,8 @@ def _scan_periods(body, carry, blocks: dict, cfg: ModelConfig, xs=None):
     held = {}
     scanned = {}
     for key, blk in blocks.items():
+        if key == "prefix":
+            continue
         experts = blk.get("moe", {}).get("experts", {})
         if "wi_q" in experts or "wi_codes" in experts:
             held[key] = experts
@@ -266,6 +390,21 @@ def _scan_periods(body, carry, blocks: dict, cfg: ModelConfig, xs=None):
 
     return jax.lax.scan(step, carry,
                         (jnp.arange(cfg.n_periods), scanned, xs))
+
+
+def _prefix_layers(params: dict, cfg: ModelConfig) -> list:
+    """Each leading dense layer's params, in order."""
+    if not cfg.first_k_dense:
+        return []
+    pre = params["blocks"]["prefix"]
+    return [jax.tree_util.tree_map(lambda a, i=i: a[i], pre)
+            for i in range(cfg.first_k_dense)]
+
+
+def _stack_entries(entries: list) -> dict:
+    """Per-layer cache entries stacked over a leading layer axis."""
+    return {name: jnp.stack([e[name] for e in entries])
+            for name in entries[0]}
 
 
 # ==========================================================================
@@ -340,6 +479,10 @@ def forward(
         enc_out = _encode(params, cfg, encoder_frames)
 
     pattern = cfg.block_pattern
+    for p in _prefix_layers(params, cfg):
+        x, _ = _self_attn_block(p, x, cfg, causal=True, positions=positions,
+                                window=window)
+        x, _ = _ffn_block(p, x, cfg, PREFIX_SPEC, collect=False)
 
     def period_body(x, period_params, layer, _):
         if cfg.seq_parallel:
@@ -454,16 +597,43 @@ def _dequant_kv(codes: jax.Array, scale: jax.Array, dtype):
     return (codes.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
+def cache_rows(cfg: ModelConfig, max_seq: int) -> int:
+    """Rows of a layer's KV cache for ``max_seq`` positions: a latent
+    cache holds whole blocks of the decode kernel."""
+    if cfg.attn_kind == "mla":
+        from repro.kernels.mla_decode import mla_cache_rows
+        return mla_cache_rows(max_seq)
+    return max_seq
+
+
+def mla_width(cfg: ModelConfig) -> int:
+    """Values a latent cache row is stored in: the latent and the rope'd
+    key part, padded to whole lanes."""
+    from repro.kernels.mla_decode import mla_cache_width
+    return mla_cache_width(cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
                dtype=None) -> dict:
-    """Decode-state pytree, stacked over periods per pattern position."""
+    """Decode-state pytree, stacked over periods per pattern position
+    (and over the leading dense layers under ``prefix``).  A latent
+    attention layer's entry is ``ckv`` [.., B, rows, width]: the normed
+    latent and the rope'd key part of every position (``rank + rope``
+    values, zero-padded to ``mla_width``)."""
     dtype = dtype or _dt(cfg)
     np_ = cfg.n_periods
     int8_kv = cfg.kv_dtype == "int8"
     cache: dict = {"pos": jnp.zeros((), jnp.int32)}
-    for i, spec in enumerate(cfg.block_pattern):
-        key = f"pos{i}"
-        if spec.mixer == "attn":
+    layers = [(f"pos{i}", spec, np_)
+              for i, spec in enumerate(cfg.block_pattern)]
+    if cfg.first_k_dense:
+        layers.append(("prefix", PREFIX_SPEC, cfg.first_k_dense))
+    for key, spec, np_ in layers:
+        if spec.mixer == "attn" and cfg.attn_kind == "mla":
+            cache[key] = {"ckv": jnp.zeros(
+                (np_, batch, cache_rows(cfg, max_seq), mla_width(cfg)),
+                dtype)}
+        elif spec.mixer == "attn":
             kv_shape = (np_, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
             kv_dt = jnp.int8 if int8_kv else dtype
             entry = {"k": jnp.zeros(kv_shape, kv_dt),
@@ -520,6 +690,38 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
 
     pattern = cfg.block_pattern
 
+    def attn_entry(p, x):
+        """Self-attention (and cross-attention) over the prompt, and the
+        layer's cache entry padded to the cache's rows."""
+        x, kv = _self_attn_block(p, x, cfg, causal=True, positions=positions,
+                                 window=window)
+        if cfg.attn_kind == "mla":
+            rows = jnp.pad(kv, ((0, 0), (0, cache_rows(cfg, max_seq) - s),
+                                (0, 0)))
+            return x, {"ckv": _pad_last(rows, mla_width(cfg)).astype(dtype)}
+        k, v = kv
+        pad = max_seq - s
+        kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        if cfg.kv_dtype == "int8":
+            kq, ks = _quant_kv(kp)
+            vq, vs = _quant_kv(vp)
+            entry = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+        else:
+            entry = {"k": kp.astype(dtype), "v": vp.astype(dtype)}
+        if cfg.is_encdec:
+            ek, ev = _enc_kv(p, enc_out, cfg)
+            x = _cross_attn_block(p, x, ek, ev, cfg)
+            entry["ck"] = ek.astype(dtype)
+            entry["cv"] = ev.astype(dtype)
+        return x, entry
+
+    prefix_entries = []
+    for p in _prefix_layers(params, cfg):
+        x, entry = attn_entry(p, x)
+        x, _ = _ffn_block(p, x, cfg, PREFIX_SPEC, collect=False)
+        prefix_entries.append(entry)
+
     def period_body(x, period_params, layer, _):
         cache_entries = {}
         auxes = []
@@ -527,24 +729,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
             p = period_params[f"pos{i}"]
             key = f"pos{i}"
             if spec.mixer == "attn":
-                x, (k, v) = _self_attn_block(
-                    p, x, cfg, causal=True, positions=positions,
-                    window=window)
-                pad = max_seq - s
-                kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                if cfg.kv_dtype == "int8":
-                    kq, ks = _quant_kv(kp)
-                    vq, vs = _quant_kv(vp)
-                    entry = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-                else:
-                    entry = {"k": kp.astype(dtype), "v": vp.astype(dtype)}
-                if cfg.is_encdec:
-                    ek, ev = _enc_kv(p, enc_out, cfg)
-                    x = _cross_attn_block(p, x, ek, ev, cfg)
-                    entry["ck"] = ek.astype(dtype)
-                    entry["cv"] = ev.astype(dtype)
-                cache_entries[key] = entry
+                x, cache_entries[key] = attn_entry(p, x)
             else:
                 h = L.rms_norm(x, p["ssm_norm"], cfg.norm_eps)
                 y, (state, conv_tail) = S.ssm_forward(
@@ -571,6 +756,8 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
     logits = unembed(params, cfg, x[:, -1])
 
     cache = dict(cache_stacked)
+    if prefix_entries:
+        cache["prefix"] = _stack_entries(prefix_entries)
     cache["pos"] = jnp.asarray(s, jnp.int32)
     aux = {"moe": aux_stacked} if aux_stacked else {}
     return logits, cache, aux
@@ -621,6 +808,106 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
     window = cfg.sliding_window if (use_window or cfg.always_swa) else None
     pattern = cfg.block_pattern
 
+    def attn_step(p, x, entry_in):
+        """Self-attention (and cross-attention) of the new token against
+        one layer's cache entry; returns the new residual and entry."""
+        if cfg.attn_kind == "mla":
+            x, ckv = _mla_decode_block(p, x, cfg, entry_in["ckv"], pos=pos,
+                                       positions=positions,
+                                       vector_pos=vector_pos)
+            return x, {"ckv": ckv}
+        with jax.named_scope("attn"):
+            h = L.rms_norm(x, p["norm"], cfg.norm_eps)
+            q, k, v = _attn_qkv(p, h, cfg)
+            q = L.apply_rope(q, positions, cfg.rope_theta)
+            k = L.apply_rope(k, positions, cfg.rope_theta)
+            S_alloc = entry_in["k"].shape[1]
+            ring = cfg.ring_kv
+            pos_w = (pos % S_alloc) if ring else pos
+
+            def write_row(buf, val):
+                # val: [B, 1, ...] — the new token's row per sequence.
+                if vector_pos:
+                    return buf.at[jnp.arange(b), pos_w].set(
+                        val[:, 0].astype(buf.dtype))
+                start = (0, pos_w) + (0,) * (buf.ndim - 2)
+                return jax.lax.dynamic_update_slice(
+                    buf, val.astype(buf.dtype), start)
+
+            if cfg.kv_dtype == "int8":
+                kq, ks = _quant_kv(k)
+                vq, vs = _quant_kv(v)
+                kc = write_row(entry_in["k"], kq)
+                vc = write_row(entry_in["v"], vq)
+                ksc = write_row(entry_in["k_scale"], ks)
+                vsc = write_row(entry_in["v_scale"], vs)
+                entry = {"k": kc, "v": vc, "k_scale": ksc,
+                         "v_scale": vsc}
+            else:
+                kc = write_row(entry_in["k"], k)
+                vc = write_row(entry_in["v"], v)
+                ksc = vsc = None
+                entry = {"k": kc, "v": vc}
+
+            # Sliding-window decode reads only the last `window` cache
+            # rows (true O(window) traffic, not a masked full read).
+            S_cache = kc.shape[1]
+            if vector_pos:
+                # Per-sequence lengths: rows diverge, so the compact
+                # dynamic-slice read doesn't apply — read the full
+                # cache and let the per-row mask in decode_attention
+                # bound each sequence's valid prefix (and window).
+                k_r, v_r = kc, vc
+                ks_r, vs_r = ksc, vsc
+                cur = jnp.minimum(pos + 1, S_cache) if ring else pos + 1
+                win_mask = None if ring else window
+            elif ring:
+                # ring buffer: every resident row is within the window;
+                # attention is permutation-invariant so wraparound
+                # order doesn't matter.
+                k_r, v_r = kc, vc
+                ks_r, vs_r = ksc, vsc
+                cur = jnp.minimum(pos + 1, S_cache)
+                win_mask = None
+            elif window is not None and S_cache > window:
+                start = jnp.clip(pos + 1 - window, 0, S_cache - window)
+                k_r = jax.lax.dynamic_slice_in_dim(kc, start, window, 1)
+                v_r = jax.lax.dynamic_slice_in_dim(vc, start, window, 1)
+                if ksc is not None:
+                    ks_r = jax.lax.dynamic_slice_in_dim(ksc, start,
+                                                        window, 1)
+                    vs_r = jax.lax.dynamic_slice_in_dim(vsc, start,
+                                                        window, 1)
+                cur = pos + 1 - start
+                win_mask = None
+            else:
+                k_r, v_r = kc, vc
+                ks_r, vs_r = ksc, vsc
+                cur = pos + 1
+                win_mask = window
+            if cfg.kv_dtype == "int8":
+                k_f = _dequant_kv(k_r, ks_r, _dt(cfg))
+                v_f = _dequant_kv(v_r, vs_r, _dt(cfg))
+            else:
+                k_f, v_f = k_r, v_r
+            o = L.decode_attention(
+                q[:, 0], k_f, v_f, cur, sliding_window=win_mask,
+                logit_softcap=cfg.logit_softcap)
+            x = x + (o.reshape(b, -1) @ p["wo"])[:, None, :]
+            if cfg.is_encdec:
+                x = _cross_attn_block(
+                    p, x, entry_in["ck"], entry_in["cv"], cfg)
+                entry["ck"] = entry_in["ck"]
+                entry["cv"] = entry_in["cv"]
+            return x, entry
+
+    prefix_entries = []
+    for i, p in enumerate(_prefix_layers(params, cfg)):
+        x, entry = attn_step(p, x, {name: leaf[i] for name, leaf
+                                    in cache["prefix"].items()})
+        x, _ = _ffn_block(p, x, cfg, PREFIX_SPEC, collect=False)
+        prefix_entries.append(entry)
+
     def period_body(x, period_params, layer, xs):
         cache_in, overrides = xs
         cache_out = {}
@@ -629,90 +916,7 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
             key = f"pos{i}"
             p = period_params[key]
             if spec.mixer == "attn":
-                with jax.named_scope("attn"):
-                    h = L.rms_norm(x, p["norm"], cfg.norm_eps)
-                    q, k, v = _attn_qkv(p, h, cfg)
-                    q = L.apply_rope(q, positions, cfg.rope_theta)
-                    k = L.apply_rope(k, positions, cfg.rope_theta)
-                    S_alloc = cache_in[key]["k"].shape[1]
-                    ring = cfg.ring_kv
-                    pos_w = (pos % S_alloc) if ring else pos
-
-                    def write_row(buf, val):
-                        # val: [B, 1, ...] — the new token's row per sequence.
-                        if vector_pos:
-                            return buf.at[jnp.arange(b), pos_w].set(
-                                val[:, 0].astype(buf.dtype))
-                        start = (0, pos_w) + (0,) * (buf.ndim - 2)
-                        return jax.lax.dynamic_update_slice(
-                            buf, val.astype(buf.dtype), start)
-
-                    if cfg.kv_dtype == "int8":
-                        kq, ks = _quant_kv(k)
-                        vq, vs = _quant_kv(v)
-                        kc = write_row(cache_in[key]["k"], kq)
-                        vc = write_row(cache_in[key]["v"], vq)
-                        ksc = write_row(cache_in[key]["k_scale"], ks)
-                        vsc = write_row(cache_in[key]["v_scale"], vs)
-                        entry = {"k": kc, "v": vc, "k_scale": ksc,
-                                 "v_scale": vsc}
-                    else:
-                        kc = write_row(cache_in[key]["k"], k)
-                        vc = write_row(cache_in[key]["v"], v)
-                        ksc = vsc = None
-                        entry = {"k": kc, "v": vc}
-
-                    # Sliding-window decode reads only the last `window` cache
-                    # rows (true O(window) traffic, not a masked full read).
-                    S_cache = kc.shape[1]
-                    if vector_pos:
-                        # Per-sequence lengths: rows diverge, so the compact
-                        # dynamic-slice read doesn't apply — read the full
-                        # cache and let the per-row mask in decode_attention
-                        # bound each sequence's valid prefix (and window).
-                        k_r, v_r = kc, vc
-                        ks_r, vs_r = ksc, vsc
-                        cur = jnp.minimum(pos + 1, S_cache) if ring else pos + 1
-                        win_mask = None if ring else window
-                    elif ring:
-                        # ring buffer: every resident row is within the window;
-                        # attention is permutation-invariant so wraparound
-                        # order doesn't matter.
-                        k_r, v_r = kc, vc
-                        ks_r, vs_r = ksc, vsc
-                        cur = jnp.minimum(pos + 1, S_cache)
-                        win_mask = None
-                    elif window is not None and S_cache > window:
-                        start = jnp.clip(pos + 1 - window, 0, S_cache - window)
-                        k_r = jax.lax.dynamic_slice_in_dim(kc, start, window, 1)
-                        v_r = jax.lax.dynamic_slice_in_dim(vc, start, window, 1)
-                        if ksc is not None:
-                            ks_r = jax.lax.dynamic_slice_in_dim(ksc, start,
-                                                                window, 1)
-                            vs_r = jax.lax.dynamic_slice_in_dim(vsc, start,
-                                                                window, 1)
-                        cur = pos + 1 - start
-                        win_mask = None
-                    else:
-                        k_r, v_r = kc, vc
-                        ks_r, vs_r = ksc, vsc
-                        cur = pos + 1
-                        win_mask = window
-                    if cfg.kv_dtype == "int8":
-                        k_f = _dequant_kv(k_r, ks_r, _dt(cfg))
-                        v_f = _dequant_kv(v_r, vs_r, _dt(cfg))
-                    else:
-                        k_f, v_f = k_r, v_r
-                    o = L.decode_attention(
-                        q[:, 0], k_f, v_f, cur, sliding_window=win_mask,
-                        logit_softcap=cfg.logit_softcap)
-                    x = x + (o.reshape(b, -1) @ p["wo"])[:, None, :]
-                    if cfg.is_encdec:
-                        x = _cross_attn_block(
-                            p, x, cache_in[key]["ck"], cache_in[key]["cv"], cfg)
-                        entry["ck"] = cache_in[key]["ck"]
-                        entry["cv"] = cache_in[key]["cv"]
-                    cache_out[key] = entry
+                x, cache_out[key] = attn_step(p, x, cache_in[key])
             else:
                 h = L.rms_norm(x, p["ssm_norm"], cfg.norm_eps)
                 y, st, cb = S.ssm_decode_step(
@@ -751,12 +955,15 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
     if policy_state is not None:
         overrides["policy_state"] = policy_state
 
-    layer_cache = {k: v for k, v in cache.items() if k != "pos"}
+    layer_cache = {k: v for k, v in cache.items()
+                   if k not in ("pos", "prefix")}
     x, (new_cache, aux_stacked) = _scan_periods(
         period_body, x, params["blocks"], cfg, (layer_cache, overrides))
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, cfg, x[:, 0])
+    if prefix_entries:
+        new_cache["prefix"] = _stack_entries(prefix_entries)
     new_cache["pos"] = pos + 1
     aux = {"moe": aux_stacked} if aux_stacked else {}
     return logits, new_cache, aux

@@ -71,6 +71,12 @@ class MoECfg:
     mlp_type: str = "swiglu"
     router_noise: float = 0.0      # jitter for load-balance during training
     aux_loss_weight: float = 0.01
+    # Whether the top-k gates are renormalised before they weight the
+    # expert outputs.  False weights them by the raw router
+    # probabilities at the selected ids (DeepSeek's norm_topk_prob
+    # false); criticality and the routing trace read the renormalised
+    # gates either way.
+    norm_topk: bool = True
 
 
 # --------------------------------------------------------------------------
@@ -337,6 +343,17 @@ def moe_apply(
 
     from repro.launch.sharding import shard_hint
 
+    mix = gates
+    if not cfg.norm_topk and gate_override is None:
+        # Raw gates: the router probabilities at the served ids, on the
+        # live selections (masked rows carry out-of-range ids).
+        live = ids < cfg.n_experts
+        if active is not None:
+            live = live & active
+        raw = jnp.take_along_axis(probs, jnp.minimum(ids, cfg.n_experts - 1),
+                                  axis=-1)
+        mix = jnp.where(live, raw, 0.0).astype(gates.dtype)
+
     cap = capacity(T, k_eff, cfg.n_experts, cfg.capacity_factor)
     positions, keep = dispatch_indices(ids, gates, cfg.n_experts, cap)
     xe = dispatch(x, ids, positions, keep, cfg.n_experts, cap)
@@ -386,7 +403,7 @@ def moe_apply(
         else:
             ye = _expert_ffn(xe, experts["wi"], experts["wo"], cfg.mlp_type)
     ye = shard_hint(ye, "model", None, None)
-    y = combine(ye, ids, positions, keep, gates)
+    y = combine(ye, ids, positions, keep, mix)
     y = shard_hint(y, ("pod", "data"), None)
 
     if cfg.n_shared_experts > 0:

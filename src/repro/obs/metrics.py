@@ -328,6 +328,10 @@ class MetricsSampler:
         self._fold_window(r.counter("cache_misses_total",
                                     "slice-cache misses"),
                           "cache_misses", u["misses"])
+        kv = getattr(eng, "kv_cache_bytes", None)
+        if kv is not None:
+            r.gauge("kv_cache_bytes",
+                    "bytes of the scheduler's batch KV cache").set(kv)
         seg = getattr(cache, "segment_summary", None)
         if callable(seg):
             for tenant, row in seg().items():

@@ -317,3 +317,68 @@ class TestFlashAttention:
         ref = flash_attention_ref(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-4)
+
+
+class TestMlaDecodeAttention:
+    """The absorbed latent-attention decode kernel against the jnp
+    oracle: 4 heads, rows of a 32-wide latent and an 8-wide rope part,
+    blocks of 16 rows, lengths that end mid-block and on a block edge."""
+
+    LENGTHS = [1, 5, 16, 37, 64]
+
+    def _inputs(self, rng, dtype, B=5, H=4, R=40, S=64):
+        ks = jax.random.split(rng, 2)
+        q = jax.random.normal(ks[0], (B, H, R)).astype(dtype)
+        ckv = jax.random.normal(ks[1], (B, S, R)).astype(dtype)
+        return q, ckv, jnp.asarray(self.LENGTHS[:B], jnp.int32)
+
+    # f32 rows: every product exact to f32 rounding.  bf16 rows: the
+    # kernel rounds the probabilities to bf16 for the value product, a
+    # relative error up to 2**-8 on each weight of values of unit scale.
+    @pytest.mark.parametrize("dtype, atol", [(jnp.float32, 1e-5),
+                                             (jnp.bfloat16, 1e-2)])
+    def test_matches_ref(self, rng, dtype, atol):
+        from repro.kernels.mla_decode.kernel import mla_decode_pallas
+        from repro.kernels.mla_decode.ref import mla_decode_attention_ref
+
+        q, ckv, lengths = self._inputs(rng, dtype)
+        out = mla_decode_pallas(q, ckv, lengths, scale=0.2, rank=32, bs=16,
+                                interpret=True)
+        ref = mla_decode_attention_ref(q, ckv, lengths, scale=0.2, rank=32)
+        assert out.shape == (5, 4, 32) and out.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=atol)
+
+    def test_blocks_past_the_length_are_not_read(self, rng):
+        """Whole blocks past a sequence's length hold NaN: none reaches
+        the output, so none was fetched into the softmax."""
+        from repro.kernels.mla_decode.kernel import mla_decode_pallas
+        from repro.kernels.mla_decode.ref import mla_decode_attention_ref
+
+        q, ckv, lengths = self._inputs(rng, jnp.float32)
+        rows = jnp.arange(64)[None, :, None]
+        last_block_end = (-(-lengths // 16) * 16)[:, None, None]
+        poisoned = jnp.where(rows >= last_block_end, jnp.nan, ckv)
+        out = mla_decode_pallas(q, poisoned, lengths, scale=0.2, rank=32,
+                                bs=16, interpret=True)
+        ref = mla_decode_attention_ref(q, ckv, lengths, scale=0.2, rank=32)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
+
+    def test_wrapper_and_cache_rows(self, rng):
+        from repro.kernels.mla_decode import (MLA_BLOCK, mla_cache_rows,
+                                              mla_cache_width,
+                                              mla_decode_attention)
+        from repro.kernels.mla_decode.ref import mla_decode_attention_ref
+
+        assert mla_cache_rows(40) == 48 and mla_cache_rows(512) == 512
+        assert mla_cache_rows(16448) == 33 * MLA_BLOCK
+        assert mla_cache_width(40) == 128 and mla_cache_width(576) == 640
+        q, ckv, lengths = self._inputs(rng, jnp.float32, S=48)
+        lengths = jnp.minimum(lengths, 48)
+        # The cache's zero lanes past the row's 40 values change nothing.
+        wide = jnp.pad(ckv, ((0, 0), (0, 0), (0, 88)))
+        out = mla_decode_attention(q, wide, lengths, scale=0.2, rank=32)
+        ref = mla_decode_attention_ref(q, ckv, lengths, scale=0.2, rank=32)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)

@@ -157,6 +157,43 @@ class TestMoEApply:
         crit_experts = np.unique(ids[crit]) if crit.any() else np.array([], int)
         assert set(np.nonzero(lsb)[0]) == set(crit_experts.tolist())
 
+    @pytest.mark.parametrize("kind", ["topk", "cache_prior", "cumsum",
+                                      None])
+    def test_raw_gates_weight_outputs_criticality_reads_renormalised(
+            self, rng, kind):
+        """``norm_topk`` false: each row's output is the renormalised
+        one times the sum of its raw router probabilities at the served
+        ids, while the selections, the traced gates and criticality do
+        not move.  A masked row stays zero either way."""
+        params = _params(rng, D, CFG)
+        x = jax.random.normal(jax.random.fold_in(rng, 7), (16, D))
+        mask = jnp.arange(16) != 5
+        kw = {"token_mask": mask}
+        if kind is not None:
+            kw.update(policy=RoutingPolicy(kind=kind, slice_mode="dbsc",
+                                           theta=0.5, cumsum_kmax=3),
+                      policy_state={"alpha": jnp.float32(0.5),
+                                    "cached_msb": jnp.arange(8) < 4,
+                                    "cached_lsb": jnp.ones(8, bool)})
+        raw_cfg = dataclasses.replace(CFG, norm_topk=False)
+        y_n, aux_n = moe_apply(params, x, CFG, **kw)
+        y_r, aux_r = moe_apply(params, x, raw_cfg, **kw)
+        for key in aux_n:
+            np.testing.assert_array_equal(np.asarray(aux_n[key]),
+                                          np.asarray(aux_r[key]))
+        probs = np.asarray(jax.nn.softmax(x @ params["w_router"], -1))
+        ids = np.asarray(aux_n["ids"])
+        live = ids < 8
+        if "active" in aux_n:
+            live &= np.asarray(aux_n["active"])
+        mass = np.where(live, np.take_along_axis(
+            probs, np.minimum(ids, 7), -1), 0.0).sum(-1)
+        assert float(mass.max()) < 0.99
+        np.testing.assert_allclose(np.asarray(y_r),
+                                   mass[:, None] * np.asarray(y_n),
+                                   rtol=1e-4, atol=1e-6)
+        assert not np.asarray(y_r)[5].any()
+
     def test_shared_expert_added(self, rng):
         cfg_s = dataclasses.replace(CFG, n_shared_experts=1, d_ff_shared=32)
         params = _params(rng, D, cfg_s)

@@ -128,3 +128,23 @@ def test_period_scan_reads_stacked_expert_codes(one_chip, no_compile_cache,
     assert "u8[4,60,2048,2816]" in hlo
     assert [b for b in PER_PERIOD_BUFFERS if b in hlo] == []
     assert "amat_expert_matmul" in hlo and "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("batch, rows", [(8, 16896), (1, 512)])
+def test_mla_decode_kernel_compiles_for_v5e(one_chip, no_compile_cache,
+                                            batch, rows):
+    """The latent-attention decode kernel at DeepSeek-V2-Lite widths: 16
+    heads over rows of a 512-wide latent and a 64-wide rope part (stored
+    in 640 lanes), the cell's batch and cache rows, and one block."""
+    from repro.kernels.mla_decode.ops import mla_decode_attention
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = jax.jit(partial(mla_decode_attention, scale=0.1147, rank=512,
+                         interpret=False))
+    compiled = fn.lower(spec((batch, 16, 576), jnp.float32),
+                        spec((batch, rows, 640), jnp.bfloat16),
+                        spec((batch,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_decode_attention" in text
